@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import time_us
-from repro.kernels import ref
+from repro.kernels import blocks, ref
 from repro.kernels.spike_matmul import spike_matmul_pallas
 
 _time = functools.partial(time_us, reps=3)
@@ -53,7 +53,8 @@ def _tile_skip_rows(emit):
         xp = np.pad(x, ((0, (-M) % bm), (0, (-K) % bk)))
         tiles = xp.reshape(xp.shape[0] // bm, bm, xp.shape[1] // bk, bk)
         skip = float(np.mean(tiles.sum(axis=(1, 3)) == 0))
-        t = time_us(lambda a: spike_matmul_pallas(a, w, bm=bm, bk=bk),
+        t = time_us(lambda a: spike_matmul_pallas(
+            a, w, bm=bm, bk=bk, interpret=blocks.interpret_mode()),
                     jnp.asarray(x), reps=smoke_reps(3, 1))
         emit(f"spike_matmul_tile_skip_{name}", t, f"skip{skip:.3f}")
 

@@ -9,7 +9,7 @@ The backend sweep times every hot-path layer kind (LIF scan, spiking
 dense matmul), every backbone, and the engine submit->result tick under
 both ``SNNConfig.backend`` settings.  On this CPU container the pallas
 rows run in interpret mode, so they are correctness/roofline anchors,
-not speed claims — flip REPRO_PALLAS_COMPILE=1 on TPU for real numbers.
+not speed claims — on a TPU the kernels run compiled.
 
 The sparse-conv sweep (``_sparse_conv_sweep``) is the exception: it
 compares the SAME interpreted kernel dense vs activity-gated across
@@ -55,8 +55,8 @@ def _sparse_conv_sweep(emit):
       mxu...  MXU-pass ratio: dense k-tile dots issued / gated dots
               issued = 1/(1-skip), deterministic from the occupancy
               mask of the real scenario data — the roofline-anchored
-              speedup a compiled TPU kernel is bounded by (flip
-              REPRO_PALLAS_COMPILE=1 on TPU for compiled wall times).
+              speedup a compiled TPU kernel is bounded by (run on
+              a TPU for compiled wall times).
 
     The jnp rows anchor the pure-XLA reference conv on the same data.
     """
@@ -128,9 +128,8 @@ def _tuned_backbone_sweep(emit):
     time, so even the tuned, activity-gated kernel pays an interpreter
     tax XLA's single fused conv does not, and xjnp plateaus well below
     1.0 regardless of sparsity.  The ``tune_conv_lif_*`` rows carry
-    the per-shape winner-vs-default margins; the compiled path
-    (REPRO_PALLAS_COMPILE=1 on TPU) is where the <=jnp comparison is
-    the roofline-fair one.
+    the per-shape winner-vs-default margins; the compiled path on a
+    TPU is where the <=jnp comparison is the roofline-fair one.
 
     ISSUE 9 adds a FOURTH executable per backbone: the whole-backbone
     megakernel (``npu_fwd_moving_bar_<name>_fused_backbone``), timed
@@ -383,7 +382,7 @@ def run(emit):
     # ingestion sweep: events/sec per DVS scenario x voxelizer backend
     # (jnp scatter vs the Pallas event_voxel kernel; interpret mode on
     # CPU, so the pallas row is a correctness/roofline anchor, not a
-    # speed claim — flip REPRO_PALLAS_COMPILE=1 on TPU)
+    # speed claim — on a TPU the kernel runs compiled)
     B, N = 8, 1024
     enc_jnp = jax.jit(lambda ev: events_to_voxel_batch(
         ev, time_steps=cfg.time_steps, height=cfg.height, width=cfg.width))

@@ -91,6 +91,9 @@ def main(argv=None) -> None:
         elif a.startswith("--only="):
             only = set(a.split("=", 1)[1].split(","))
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     rows = []
     print("name,us_per_call,derived")
 

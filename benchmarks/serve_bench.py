@@ -16,7 +16,7 @@ On a multi-device host (the CI serving-smoke lane forces 8 host
 devices) the tick batch is sharded over the ``("data",)`` serving
 mesh; the ``ndev`` tag in every row records the mesh extent.  On this
 CPU container the pallas rows run in interpret mode — correctness
-anchors, not speed claims (REPRO_PALLAS_COMPILE=1 on TPU).
+anchors, not speed claims (on a TPU the kernels run compiled).
 """
 from __future__ import annotations
 

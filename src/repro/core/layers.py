@@ -315,8 +315,8 @@ def max_pool(x, window: int = 2, cfg: Optional[SNNConfig] = None):
     T, B, H, W, C = x.shape
     xf = jnp.swapaxes(x, 0, 1).reshape(B * T, H, W, C)
     if cfg is not None and _check_backend(cfg):
-        from repro.kernels import ops
-        if not ops.INTERPRET:
+        from repro.kernels import blocks, ops
+        if not blocks.interpret_mode():
             y = ops.max_pool_op(xf, window=window)
             return jnp.swapaxes(
                 y.reshape(B, T, H // window, W // window, C), 0, 1)

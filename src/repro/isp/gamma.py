@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.blocks import matmul_precision
+
 LUT_SIZE = 256
 
 
@@ -25,6 +27,22 @@ def apply_gamma(rgb, lut: jax.Array) -> jax.Array:
     lo = jnp.take(lut, idx)
     hi = jnp.take(lut, jnp.minimum(idx + 1, LUT_SIZE - 1))
     return lo + frac * (hi - lo)          # linear-interp LUT, like the HDL
+
+
+def gamma_tile(rgb, p, consts=()):
+    """Fused-ISP form of ``apply_gamma(rgb, gamma_lut(p["gamma"]))``.
+    A Pallas TPU kernel cannot gather from a table, so the two LUT
+    entries around each pixel are evaluated in place:
+    ``linspace(0, 1, LUT_SIZE)[i]`` is exactly ``i / (LUT_SIZE - 1)``
+    in float32, raised to the same power, then interpolated in the
+    reference's order."""
+    inv = 1.0 / jnp.maximum(p["gamma"], 1e-3)
+    idx = jnp.clip((rgb * (LUT_SIZE - 1)).astype(jnp.int32), 0, LUT_SIZE - 1)
+    frac = rgb * (LUT_SIZE - 1) - idx
+    nxt = jnp.minimum(idx + 1, LUT_SIZE - 1)
+    lo = (idx.astype(jnp.float32) / (LUT_SIZE - 1)) ** inv
+    hi = (nxt.astype(jnp.float32) / (LUT_SIZE - 1)) ** inv
+    return lo + frac * (hi - lo)
 
 
 _RGB2YCBCR = jnp.array([[0.299, 0.587, 0.114],
@@ -74,12 +92,17 @@ def sharpen_window(win, p, *, bh: int, bw: int, consts=SHARPEN_CONSTS,
     exact) and the cross blur replays the reference's summation
     order — bit-identical to :func:`sharpen_luma`."""
     mat, off, inv = consts
-    ycc = jnp.einsum("...c,dc->...d", win, mat) + off
+    prec = matmul_precision()     # a TPU kernel's default is one bf16 pass
+    ycc = jnp.einsum("...c,dc->...d", win, mat, precision=prec) + off
     y = ycc[..., 0]
     # roll(y, 1, 0)[i] == y[i - 1]: same up/down/left/right fold order
     y_c = y[1:-1, 1:-1]
     blur = (y_c + y[0:-2, 1:-1] + y[2:, 1:-1]
             + y[1:-1, 0:-2] + y[1:-1, 2:]) / 5.0
     y2 = jnp.clip(y_c + p["amount"] * (y_c - blur), 0.0, 1.0)
-    ycc_c = ycc[1:-1, 1:-1].at[..., 0].set(y2) - off
-    return jnp.clip(jnp.einsum("...c,dc->...d", ycc_c, inv), 0.0, 1.0)
+    ycc_c = ycc[1:-1, 1:-1]
+    # replace the luma channel (a stack, not a scatter: a TPU kernel
+    # has no scatter)
+    ycc_c = jnp.stack([y2, ycc_c[..., 1], ycc_c[..., 2]], axis=-1) - off
+    return jnp.clip(jnp.einsum("...c,dc->...d", ycc_c, inv,
+                               precision=prec), 0.0, 1.0)

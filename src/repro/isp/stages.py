@@ -71,7 +71,8 @@ from repro.isp.awb import (AWB_STATS_WIDTH, apply_wb, awb_apply_stats,
 from repro.isp.demosaic import DEMOSAIC_RADIUS, demosaic_mhc, demosaic_window
 from repro.isp.dpc import DPC_RADIUS, dpc_correct, dpc_window
 from repro.isp.gamma import (SHARPEN_CONSTS, SHARPEN_RADIUS, apply_gamma,
-                             gamma_lut, sharpen_luma, sharpen_window)
+                             gamma_lut, gamma_tile, sharpen_luma,
+                             sharpen_window)
 from repro.isp.nlm import NLM_RADIUS, nlm_denoise, nlm_window
 from repro.isp.tone import (CCM_CONSTS, apply_saturation,
                             apply_saturation_tile,
@@ -413,7 +414,7 @@ register_stage(
     doc="bounded-window non-local-means denoise (§V-B.4)")
 register_stage(
     "gamma", (ParamSpec("gamma", 0.4, 3.0, 2.2),), _gamma,
-    kind="pointwise",
+    kind="pointwise", tile_fn=gamma_tile,
     doc="256-entry gamma LUT with linear interp (§V-B.5)")
 register_stage(
     "sharpen", (ParamSpec("amount", 0.0, 1.0, 0.3),), _sharpen,

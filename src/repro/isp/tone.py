@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.isp.gamma import _RGB2YCBCR
+from repro.kernels.blocks import matmul_precision
 
 _LUMA = _RGB2YCBCR[0]                                    # BT.601 luma row
 
@@ -40,5 +41,7 @@ CCM_CONSTS = (_LUMA,)
 
 
 def apply_saturation_tile(rgb, p, consts=CCM_CONSTS) -> jax.Array:
-    lum = jnp.einsum("...c,c->...", rgb, consts[0])[..., None]
+    # a TPU kernel's default contraction is one bf16 pass
+    lum = jnp.einsum("...c,c->...", rgb, consts[0],
+                     precision=matmul_precision())[..., None]
     return jnp.clip(lum + p["saturation"] * (rgb - lum), 0.0, 1.0)

@@ -74,7 +74,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.layers import _same_pads
-from repro.kernels.blocks import CANONICAL_K_BLOCK, DEFAULT_BM
+from repro.kernels.blocks import (CANONICAL_K_BLOCK, DEFAULT_BM,
+                                  matmul_precision)
 from repro.kernels.lif_scan import norm_affine_lif_epilogue
 from repro.launch.roofline import VMEM_BYTES, vmem_residency_estimate
 
@@ -337,7 +338,8 @@ def _im2col_resident(act, kernel: int, stride: int):
     return p.reshape(T * ho * wo, kernel * kernel * C), (ho, wo)
 
 
-def _mac_canonical(patches, w_ref, acc_ref, *, bm: int, inline: bool):
+def _mac_canonical(patches, w_ref, acc_ref, *, bm: int, inline: bool,
+                   precision):
     """Row-chunked, canonical-K-blocked MAC of the resident patch
     matrix into the f32 accumulator scratch — the same accumulation
     order as ``blocked_matmul`` (per output element: K blocks in
@@ -359,7 +361,7 @@ def _mac_canonical(patches, w_ref, acc_ref, *, bm: int, inline: bool):
             def _mac(tile=tile, r0=r0, r1=r1, c0=c0, c1=c1):
                 acc_ref[r0:r1, :] += jnp.dot(
                     tile.astype(jnp.float32),
-                    w_ref[c0:c1, :].astype(jnp.float32),
+                    w_ref[c0:c1, :].astype(jnp.float32), precision=precision,
                     preferred_element_type=jnp.float32)
 
 
@@ -380,7 +382,7 @@ def _mac_depthwise(patches3, w_ref, acc_ref, *, inline: bool):
 
 def _segment_kernel(*refs, specs: Tuple[LayerSpec, ...], T: int, H: int,
                     W: int, bm: int, inline: bool, tau: float,
-                    v_th: float, v_reset: float, eps: float):
+                    v_th: float, v_reset: float, eps: float, precision):
     """One grid step = one batch element through the WHOLE segment.
     refs: [x, (w, scale, bias) per layer, out, (acc, s, u) per layer]."""
     L = len(specs)
@@ -404,7 +406,8 @@ def _segment_kernel(*refs, specs: Tuple[LayerSpec, ...], T: int, H: int,
             pk = (-patches.shape[1]) % CANONICAL_K_BLOCK
             if pk:
                 patches = jnp.pad(patches, ((0, 0), (0, pk)))
-            _mac_canonical(patches, w_ref, acc_ref, bm=bm, inline=inline)
+            _mac_canonical(patches, w_ref, acc_ref, bm=bm, inline=inline,
+                           precision=precision)
         # layer k's epilogue runs on the resident accumulator and its
         # spikes feed layer k+1's im2col without touching HBM — the
         # layer-chained VMEM residency this module exists for
@@ -423,7 +426,7 @@ def _segment_kernel(*refs, specs: Tuple[LayerSpec, ...], T: int, H: int,
 def backbone_segment_pallas(x, flat_params, *, specs, tau: float,
                             v_th: float, v_reset: float, eps: float,
                             gate: str = "inline", bm: int = DEFAULT_BM,
-                            interpret: bool = True):
+                            interpret: bool):
     """Run one planned segment as ONE Pallas kernel.
 
     x: [T, B, H, W, C] spike input; ``flat_params``: per layer
@@ -473,7 +476,8 @@ def backbone_segment_pallas(x, flat_params, *, specs, tau: float,
     return pl.pallas_call(
         functools.partial(_segment_kernel, specs=tuple(specs), T=T, H=H,
                           W=W, bm=bm, inline=(gate == "inline"), tau=tau,
-                          v_th=v_th, v_reset=v_reset, eps=eps),
+                          v_th=v_th, v_reset=v_reset, eps=eps,
+                          precision=matmul_precision()),
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((T, 1, h, w, cf),
@@ -488,14 +492,29 @@ def backbone_segment_pallas(x, flat_params, *, specs, tau: float,
 # Gated spike max-pool (the standalone pooling kernel)
 # ---------------------------------------------------------------------------
 
+def _pool_block(x_ref, window: int, c: int):
+    """Max-pool of one frame laid out ``[1, ho, window, wo, window·C]``
+    (a free reshape of ``[H, W, C]``): the H offset is a static index
+    on a leading dim and the W offset a static lane slice, so the
+    window's values come out of VMEM without strided reads or gathers.
+    Max has no rounding: the result is ``lax.reduce_window``'s."""
+    out = None
+    for di in range(window):
+        row = x_ref[0, :, di]               # [ho, wo, window·C]
+        for dj in range(window):
+            s = row[:, :, dj * c:(dj + 1) * c]
+            out = s if out is None else jnp.maximum(out, s)
+    return out[None]
+
+
 def _max_pool_kernel(x_ref, y_ref, *, window: int, gated: bool):
-    x = x_ref[0]                            # [H, W, C]
+    c = y_ref.shape[-1]
     if gated:
-        live = jnp.any(x != 0)
+        live = jnp.any(x_ref[...] != 0)
 
         @pl.when(live)
         def _pool():
-            y_ref[...] = _pool_slices(x[None], window)
+            y_ref[...] = _pool_block(x_ref, window, c)
 
         @pl.when(jnp.logical_not(live))
         def _zero():
@@ -503,11 +522,11 @@ def _max_pool_kernel(x_ref, y_ref, *, window: int, gated: bool):
             # are non-negative — see max_pool_pallas docstring)
             y_ref[...] = jnp.zeros_like(y_ref)
     else:
-        y_ref[...] = _pool_slices(x[None], window)
+        y_ref[...] = _pool_block(x_ref, window, c)
 
 
 def max_pool_pallas(xf, *, window: int = 2, gated: bool = True,
-                    interpret: bool = True):
+                    interpret: bool):
     """Gated spike max-pool.  xf: [N, H, W, C] folded SPIKE tensor ->
     [N, H//window, W//window, C], bit-exact vs ``lax.reduce_window``
     (max, VALID, stride = window).
@@ -520,13 +539,15 @@ def max_pool_pallas(xf, *, window: int = 2, gated: bool = True,
     standalone kernel serves the unfused path on compiled backends."""
     N, H, W, C = xf.shape
     ho, wo = H // window, W // window
+    x5 = xf[:, :ho * window, :wo * window].reshape(N, ho, window, wo,
+                                                   window * C)
 
     return pl.pallas_call(
         functools.partial(_max_pool_kernel, window=window, gated=gated),
         grid=(N,),
-        in_specs=[pl.BlockSpec((1, H, W, C), lambda i: (i, 0, 0, 0))],
+        in_specs=[pl.BlockSpec((1, ho, window, wo, window * C),
+                               lambda i: (i, 0, 0, 0, 0))],
         out_specs=pl.BlockSpec((1, ho, wo, C), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, ho, wo, C), xf.dtype),
-        scratch_shapes=[],
         interpret=interpret,
-    )(xf)
+    )(x5)

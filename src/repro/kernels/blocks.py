@@ -33,8 +33,13 @@ order per layer is byte-for-byte the one this module pins.  Its
 swept row-chunk sizes (``bm`` ∈ {128, 256, 512}) start from
 ``DEFAULT_BM`` below.
 
-This module is import-light on purpose (no jax, no pallas): the
-pure-jnp reference path imports it without pulling the kernel stack in.
+It also owns the two platform decisions every kernel takes at trace
+time: whether Pallas runs compiled or interpreted, and the matmul
+precision (``interpret_mode`` / ``matmul_precision``).
+
+This module is import-light on purpose (no pallas; jax only inside the
+platform functions): the pure-jnp reference path imports it without
+pulling the kernel stack in.
 """
 from __future__ import annotations
 
@@ -45,6 +50,27 @@ from typing import List, Tuple
 # changes last-bit rounding of every spike conv/matmul — bump
 # ``repro.kernels.tune.KERNELS_VERSION`` if you ever do.
 CANONICAL_K_BLOCK = 128
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: exactly when JAX's
+    default backend is not a TPU.  Called at trace time, never at
+    import (importing must not touch device state)."""
+    import jax
+    return jax.default_backend() != "tpu"
+
+
+def matmul_precision():
+    """The one matmul precision of the serving path, for the Pallas
+    kernels' dots and (through ``EngineCore``) the jnp backend alike, so
+    the fallback ladder's rungs compute the same numbers.  On a TPU:
+    full float32 (``"highest"``) — the default there is one bfloat16
+    pass, which rounds every weight to 8 mantissa bits (spikes are
+    exact in bf16, weights are not).  Elsewhere: ``None``, XLA's
+    default, already full float32 on the CPU (asking for ``"highest"``
+    there changes the CPU dot path and its summation order)."""
+    return None if interpret_mode() else "highest"
+
 
 # Default launch tile shapes (MXU-native 128x128) — what dispatch uses
 # when no tuning-table entry covers a shape.

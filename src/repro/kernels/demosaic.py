@@ -1,10 +1,8 @@
 """Pallas TPU kernel: Malvar-He-Cutler demosaic, tiled with VMEM halos.
 
-The FPGA streams rows through 5-line buffers; the TPU tile reads a
-(bh+4, bw+4) halo'd window from the mosaic kept in VMEM and emits a
-(bh, bw, 3) RGB tile.  The mosaic stays unblocked in VMEM (a 1k x 1k
-fp32 frame is 4 MB < 16 MB VMEM); compute is tiled over the grid so the
-working set per step stays register-friendly.
+The FPGA streams rows through 5-line buffers; each grid step reads one
+(bh+4, bw+4) halo'd window of the zero-padded mosaic (cut by the
+wrapper, ``isp_fused.halo_windows``) and emits a (bh, bw, 3) RGB tile.
 """
 from __future__ import annotations
 
@@ -15,21 +13,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.isp.demosaic import _F_G, _F_RB_COL, _F_RB_DIAG, _F_RB_ROW
+from repro.kernels.isp_fused import halo_windows
 
 BH, BW = 128, 128
 
 
 def _demosaic_kernel(raw_ref, out_ref, *, bh: int, bw: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    H, W = raw_ref.shape
-    # halo'd window (clamped dynamic slice; border tiles replicate edge)
-    y0 = i * bh
-    x0 = j * bw
-    # zero halo pad — matches the reference conv's SAME zero padding
-    win = jax.lax.dynamic_slice(
-        jnp.pad(raw_ref[...], ((2, 2), (2, 2))),
-        (y0, x0), (bh + 4, bw + 4))
+    y0 = pl.program_id(0) * bh
+    x0 = pl.program_id(1) * bw
+    win = raw_ref[...]                 # zero halo: SAME conv padding
 
     def conv5(kern):
         acc = jnp.zeros((bh, bw), jnp.float32)
@@ -65,19 +57,21 @@ def _demosaic_kernel(raw_ref, out_ref, *, bh: int, bw: int):
 
 
 def demosaic_pallas(raw, *, bh: int = BH, bw: int = BW,
-                    interpret: bool = True):
+                    interpret: bool):
     """raw: [H, W] RGGB in [0,1] -> RGB [H, W, 3]."""
     H, W = raw.shape
     ph, pw = (-H) % bh, (-W) % bw
     rp = jnp.pad(raw, ((0, ph), (0, pw))) if (ph or pw) else raw
     Hp, Wp = H + ph, W + pw
 
+    wins = halo_windows(jnp.pad(rp, ((2, 2), (2, 2))), bh, bw, 2)
     out = pl.pallas_call(
         functools.partial(_demosaic_kernel, bh=bh, bw=bw),
         grid=(Hp // bh, Wp // bw),
-        in_specs=[pl.BlockSpec((Hp, Wp), lambda i, j: (0, 0))],
+        in_specs=[pl.BlockSpec((None, None, bh + 4, bw + 4),
+                               lambda i, j: (i, j, 0, 0))],
         out_specs=pl.BlockSpec((bh, bw, 3), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((Hp, Wp, 3), raw.dtype),
         interpret=interpret,
-    )(rp)
+    )(wins)
     return out[:H, :W]

@@ -55,7 +55,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 128,
-                           bk: int = 128, interpret: bool = True):
+                           bk: int = 128, interpret: bool):
     """q: [BH, Sq, d]; k, v: [BH, Sk, d(v)] -> [BH, Sq, dv].
 
     Heads folded into the leading dim (GQA repeat handled by the ops

@@ -70,7 +70,7 @@ def _lif_kernel(i_ref, s_ref, u_ref, *, tau: float, v_th: float,
 
 def lif_scan_pallas(currents, *, tau: float = 2.0, v_th: float = 1.0,
                     v_reset: float = 0.0, block_n: int = BLOCK_N,
-                    interpret: bool = True):
+                    interpret: bool):
     """currents: [T, N] -> spikes [T, N] (forward only; the custom-VJP
     wrapper ``repro.kernels.ops.lif_scan_op`` adds the surrogate-grad
     backward so this path is legal under BPTT training)."""
@@ -117,15 +117,18 @@ def norm_affine_lif_epilogue(y, scale, bias, s_ref, u_ref, *,
     mu = jnp.mean(y, axis=(0, 2), keepdims=True)
     var = jnp.var(y, axis=(0, 2), keepdims=True)
     z = (y - mu) * jax.lax.rsqrt(var + eps)
-    z = z * scale + bias
+    # the affine currents wait in the spike block: step t reads its
+    # input from the ref at a leading-dim index (Mosaic cannot slice a
+    # value at a dynamic index) and overwrites it with its spikes
+    s_ref[...] = (z * scale + bias).astype(s_ref.dtype)
 
     u_ref[...] = jnp.full_like(u_ref, v_reset)
 
     def step(t, _):
-        u = decay * (u_ref[...] - v_reset) + v_reset + z[t]
+        u = decay * (u_ref[...] - v_reset) + v_reset + s_ref[t]
         s = ((u - v_th) >= 0).astype(u.dtype)
         u_ref[...] = u * (1.0 - s) + v_reset * s
-        s_ref[t, ...] = s
+        s_ref[t] = s
         return 0
 
     jax.lax.fori_loop(0, T, step, 0)
@@ -141,7 +144,7 @@ def _norm_lif_kernel(y_ref, scale_ref, bias_ref, s_ref, u_ref, *,
 
 def norm_affine_lif_pallas(y, scale, bias, *, tau: float = 2.0,
                            v_th: float = 1.0, v_reset: float = 0.0,
-                           eps: float = 1e-6, interpret: bool = True):
+                           eps: float = 1e-6, interpret: bool):
     """Fused spiking-conv epilogue.  y: [T, B, HW, C] pre-norm currents;
     scale, bias: [C] -> spikes [T, B, HW, C].
 

@@ -17,20 +17,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.isp_fused import halo_windows
+
 HALO = 4   # 3 (search radius) + 1 (patch radius)
 
 
 def _nlm_kernel(lum_ref, chan_ref, h_ref, out_ref, *, bh: int, bw: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
     hp = h_ref[0]
-    lpad = jnp.pad(lum_ref[...], ((HALO, HALO), (HALO, HALO)), mode="wrap")
-    cpad_full = jnp.pad(chan_ref[...], ((HALO, HALO), (HALO, HALO)),
-                        mode="wrap")
-    lwin = jax.lax.dynamic_slice(lpad, (i * bh, j * bw),
-                                 (bh + 2 * HALO, bw + 2 * HALO))
-    cwin = jax.lax.dynamic_slice(cpad_full, (i * bh, j * bw),
-                                 (bh + 2 * HALO, bw + 2 * HALO))
+    lwin, cwin = lum_ref[...], chan_ref[...]     # wrap-halo'd windows
 
     def box3(x):
         s = x[0:-2] + x[1:-1] + x[2:]
@@ -53,7 +47,7 @@ def _nlm_kernel(lum_ref, chan_ref, h_ref, out_ref, *, bh: int, bw: int):
 
 
 def nlm_pallas(img, strength, *, bh: int = 128, bw: int = 128,
-               interpret: bool = True):
+               interpret: bool):
     """img: [H, W] or [H, W, C] in [0,1]; strength scalar in [0,1].
     Requires H % bh == W % bw == 0 (wrap halo must wrap the true image).
     """
@@ -65,15 +59,22 @@ def nlm_pallas(img, strength, *, bh: int = 128, bw: int = 128,
     lum = jnp.mean(chans, axis=-1)
     h = jnp.atleast_1d(1e-3 + 0.2 * jnp.asarray(strength, jnp.float32))
 
+    def windows(plane):
+        return halo_windows(jnp.pad(plane, HALO, mode="wrap"), bh, bw,
+                            HALO)
+
+    win_spec = pl.BlockSpec((None, None, bh + 2 * HALO, bw + 2 * HALO),
+                            lambda i, j: (i, j, 0, 0))
     call = pl.pallas_call(
         functools.partial(_nlm_kernel, bh=bh, bw=bw),
         grid=(H // bh, W // bw),
-        in_specs=[pl.BlockSpec((H, W), lambda i, j: (0, 0)),
-                  pl.BlockSpec((H, W), lambda i, j: (0, 0)),
+        in_specs=[win_spec, win_spec,
                   pl.BlockSpec((1,), lambda i, j: (0,))],
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((H, W), img.dtype),
         interpret=interpret,
     )
-    out = jnp.stack([call(lum, chans[..., c], h) for c in range(C)], -1)
+    lwins = windows(lum)
+    out = jnp.stack([call(lwins, windows(chans[..., c]), h)
+                     for c in range(C)], -1)
     return out[..., 0] if single else out

@@ -1,7 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; on TPU set
-REPRO_PALLAS_COMPILE=1 to lower natively via Mosaic).
+Every wrapper passes ``interpret=blocks.interpret_mode()``: the kernels
+lower through Mosaic when the default backend is a TPU and run in the
+Pallas interpreter everywhere else.  The decision is taken at trace
+time, never at import.
 
 The ISP stage registry's "pallas" backend resolves to ``demosaic_op``
 and ``nlm_op`` here (lazily, from repro.isp.stages, so the pure-jnp
@@ -42,7 +44,6 @@ composition, per the tuned config.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +51,7 @@ import jax.numpy as jnp
 from repro.core.layers import (_same_pads, blocked_matmul, dw_patches,
                                max_pool, spike_conv_jnp, spike_im2col)
 from repro.core.lif import lif_scan as lif_scan_ref
-from repro.kernels import tune
+from repro.kernels import blocks, tune
 from repro.kernels.backbone_fuse import (backbone_segment_pallas,
                                          max_pool_pallas, segment_macs,
                                          segment_activation_elems,
@@ -67,8 +68,6 @@ from repro.kernels.spike_conv import (occupancy_mask, spike_conv_lif_pallas,
                                       spike_conv_pallas, spike_dwconv_pallas,
                                       tap_occupancy_mask)
 from repro.kernels.spike_matmul import spike_matmul_pallas
-
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
 NORM_EPS = 1e-6
 
@@ -93,7 +92,7 @@ def event_voxel_op(events, *, time_steps: int, height: int, width: int,
         events.y.astype(jnp.int32), events.p.astype(jnp.int32),
         events.valid.astype(jnp.int32), time_steps=time_steps,
         height=height, width=width, window=window, mode=mode, oob=oob,
-        block_t=block_t, interpret=INTERPRET)
+        block_t=block_t, interpret=blocks.interpret_mode())
 
 
 # ---------------------------------------------------------------------------
@@ -154,7 +153,7 @@ def _lif_scan(currents, tau, v_th, v_reset, beta, block_n):
     T = currents.shape[0]
     out = lif_scan_pallas(currents.reshape(T, -1), tau=tau, v_th=v_th,
                           v_reset=v_reset, block_n=block_n,
-                          interpret=INTERPRET)
+                          interpret=blocks.interpret_mode())
     return out.reshape(currents.shape)
 
 
@@ -210,7 +209,7 @@ def _norm_affine_lif(y, scale, bias, tau, v_th, v_reset, beta):
     y4 = y.reshape(T, B, -1, C)
     out = norm_affine_lif_pallas(y4, scale, bias, tau=tau, v_th=v_th,
                                  v_reset=v_reset, eps=NORM_EPS,
-                                 interpret=INTERPRET)
+                                 interpret=blocks.interpret_mode())
     return out.reshape(y.shape)
 
 
@@ -273,7 +272,7 @@ def norm_affine_lif_op(y, scale, bias, *, tau: float = 2.0,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _spike_matmul(x, w, bm, bk, bn):
     return spike_matmul_pallas(x, w, bm=bm, bk=bk, bn=bn,
-                               interpret=INTERPRET)
+                               interpret=blocks.interpret_mode())
 
 
 def _spike_matmul_fwd(x, w, bm, bk, bn):
@@ -323,9 +322,10 @@ def _spike_conv_mm(patches, wmat, gate, bm, bk, bn):
         # route through the existing tile-skip spike matmul (per-tile
         # jnp.any check inside the kernel)
         return spike_matmul_pallas(patches, wmat, bm=bm, bk=bk, bn=bn,
-                                   interpret=INTERPRET)
+                                   interpret=blocks.interpret_mode())
     return spike_conv_pallas(patches, wmat, gated=(gate == "mask"),
-                             bm=bm, bk=bk, bn=bn, interpret=INTERPRET)
+                             bm=bm, bk=bk, bn=bn,
+                             interpret=blocks.interpret_mode())
 
 
 def _spike_conv_mm_fwd(patches, wmat, gate, bm, bk, bn):
@@ -351,7 +351,7 @@ _spike_conv_mm.defvjp(_spike_conv_mm_fwd, _spike_conv_mm_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _spike_dwconv(patches3, wflat, gate, bm):
     return spike_dwconv_pallas(patches3, wflat, gated=(gate != "none"),
-                               bm=bm, interpret=INTERPRET)
+                               bm=bm, interpret=blocks.interpret_mode())
 
 
 def _spike_dwconv_fwd(patches3, wflat, gate, bm):
@@ -450,7 +450,7 @@ def _conv_lif(patches, wmat, scale, bias, T, B, HW, gate, bm, tau, v_th,
     return spike_conv_lif_pallas(
         patches, wmat, scale, bias, T=T, B=B, HW=HW, tau=tau, v_th=v_th,
         v_reset=v_reset, eps=NORM_EPS, gate=gate, bm=bm,
-        interpret=INTERPRET)
+        interpret=blocks.interpret_mode())
 
 
 def _conv_lif_fwd(patches, wmat, scale, bias, T, B, HW, gate, bm, tau,
@@ -607,7 +607,7 @@ def _backbone_seg(x, params, specs, gate, bm, tau, v_th, v_reset, beta):
     return backbone_segment_pallas(
         x, _seg_prep(params, specs), specs=specs, tau=tau, v_th=v_th,
         v_reset=v_reset, eps=NORM_EPS, gate=gate, bm=bm,
-        interpret=INTERPRET)
+        interpret=blocks.interpret_mode())
 
 
 def _backbone_seg_fwd(x, params, specs, gate, bm, tau, v_th, v_reset,
@@ -646,7 +646,7 @@ def _pool_spikes(x, window: int):
     kernel-backed when compiled, reduce_window under the interpreter
     (bit-identical; a standalone interpret-mode launch is a net loss —
     fused segments absorb pooling as an in-kernel epilogue instead)."""
-    if INTERPRET:
+    if blocks.interpret_mode():
         return max_pool(x, window)
     T, B, H, W, C = x.shape
     xf = jnp.swapaxes(x, 0, 1).reshape(B * T, H, W, C)
@@ -746,7 +746,7 @@ def _pool_ref(xf, window: int):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _max_pool_k(xf, window, gated):
     return max_pool_pallas(xf, window=window, gated=gated,
-                           interpret=INTERPRET)
+                           interpret=blocks.interpret_mode())
 
 
 def _max_pool_k_fwd(xf, window, gated):
@@ -801,7 +801,7 @@ def pointwise_segment_op(x, pvec, stats, consts=(), *, chain,
     need (e.g. the CCM luma row)."""
     return pointwise_segment_pallas(x, pvec, stats, chain=chain,
                                     consts=tuple(consts), bh=bh, bw=bw,
-                                    interpret=INTERPRET)
+                                    interpret=blocks.interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -817,20 +817,21 @@ def stencil_segment_op(x, pvec, stats, consts=(), *, prologue, window_fn,
     return stencil_segment_pallas(
         x, pvec, stats, prologue=prologue, window_fn=window_fn,
         wstep=wstep, radius=radius, pad=pad, out_tail=out_tail,
-        consts=tuple(consts), bh=bh, bw=bw, interpret=INTERPRET)
+        consts=tuple(consts), bh=bh, bw=bw,
+        interpret=blocks.interpret_mode())
 
 
 @jax.jit
 def demosaic_op(raw):
-    return demosaic_pallas(raw, interpret=INTERPRET)
+    return demosaic_pallas(raw, interpret=blocks.interpret_mode())
 
 
 @jax.jit
 def nlm_op(img, strength):
-    return nlm_pallas(img, strength, interpret=INTERPRET)
+    return nlm_pallas(img, strength, interpret=blocks.interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("causal",))
 def flash_attention_op(q, k, v, causal: bool = True):
     return flash_attention_pallas(q, k, v, causal=causal,
-                                  interpret=INTERPRET)
+                                  interpret=blocks.interpret_mode())

@@ -67,8 +67,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.blocks import (CANONICAL_K_BLOCK, DEFAULT_BK,
                                   DEFAULT_BM, DEFAULT_BN,
-                                  canonical_k_slices)
+                                  canonical_k_slices, matmul_precision)
 from repro.kernels.lif_scan import norm_affine_lif_epilogue
+from repro.launch.roofline import VMEM_LIMIT_BYTES
 
 # Default MXU tile sizes (re-exported from repro.kernels.blocks — the
 # single source of truth shared with the jnp reference's K-block,
@@ -101,21 +102,24 @@ def tap_occupancy_mask(patches3, *, bm: int = BM):
 
 
 def _conv_kernel(occ_ref, x_ref, w_ref, y_ref, acc_ref, *, k_steps: int,
-                 bk: int):
-    k = pl.program_id(2)
+                 bk: int, precision):
+    i, k = pl.program_id(0), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(occ_ref[0, 0] != 0)          # activity gate: precomputed bit
+    # activity gate: the tile's precomputed occupancy bit, read from the
+    # scalar-prefetched (SMEM) mask
+    @pl.when(occ_ref[i * k_steps + k] != 0)
     def _mac():
         # accumulate the launch K-step in canonical sub-blocks so any
-        # tuned bk reproduces the reference accumulation order bit-for-
-        # bit (repro.kernels.blocks — the bit-parity contract)
+        # tuned bk reproduces the reference accumulation order (repro.
+        # kernels.blocks — the parity contract)
         for c0, c1 in canonical_k_slices(bk):
             acc_ref[...] += jnp.dot(x_ref[:, c0:c1].astype(jnp.float32),
                                     w_ref[c0:c1, :].astype(jnp.float32),
+                                    precision=precision,
                                     preferred_element_type=jnp.float32)
 
     @pl.when(k == k_steps - 1)
@@ -124,7 +128,7 @@ def _conv_kernel(occ_ref, x_ref, w_ref, y_ref, acc_ref, *, k_steps: int,
 
 
 def spike_conv_pallas(patches, wmat, *, gated: bool = True, bm: int = BM,
-                      bk: int = BK, bn: int = BN, interpret: bool = True):
+                      bk: int = BK, bn: int = BN, interpret: bool):
     """patches: [M, K] spike patch matrix, wmat: [K, N] -> patches @ wmat
     with occupancy-gated K-steps.  ``gated=False`` runs the identical
     kernel with an all-ones mask — the dense baseline the benchmark
@@ -145,43 +149,48 @@ def spike_conv_pallas(patches, wmat, *, gated: bool = True, bm: int = BM,
         occ = jnp.ones((Mp // bm, k_steps), jnp.int32)
 
     y = pl.pallas_call(
-        functools.partial(_conv_kernel, k_steps=k_steps, bk=bk),
-        grid=(Mp // bm, Np // bn, k_steps),
-        in_specs=[pl.BlockSpec((1, 1), lambda i, j, k: (i, k)),
-                  pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-                  pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        functools.partial(_conv_kernel, k_steps=k_steps, bk=bk,
+                          precision=matmul_precision()),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Mp // bm, Np // bn, k_steps),
+            in_specs=[pl.BlockSpec((bm, bk), lambda i, j, k, occ: (i, k)),
+                      pl.BlockSpec((bk, bn), lambda i, j, k, occ: (k, j))],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, occ: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), wmat.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(occ, x, w)
+    )(occ.reshape(-1), x, w)
     return y[:M, :N]
 
 
 def _dwconv_kernel(occ_ref, x_ref, w_ref, y_ref, acc_ref, *, taps: int):
+    i = pl.program_id(0)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     for t in range(taps):                  # static K-loop over taps
 
-        @pl.when(occ_ref[0, t] != 0)       # gate: skip silent tap slabs
+        @pl.when(occ_ref[i * taps + t] != 0)   # skip silent tap slabs
         def _mac(t=t):
-            acc_ref[...] += x_ref[:, t, :] * w_ref[t, :]
+            acc_ref[...] += x_ref[t] * w_ref[t:t + 1, :]
 
     y_ref[...] = acc_ref[...].astype(y_ref.dtype)
 
 
 def spike_dwconv_pallas(patches3, wflat, *, gated: bool = True,
                         bm: int = BM, lane: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """patches3: [M, taps, C] per-channel spike patches, wflat: [taps, C]
     -> [M, C] depthwise conv output (sum over taps of x[:, t, :] * w[t]).
     Accumulates taps in the same order as the jnp tap loop
     (``repro.core.layers.spike_conv_jnp``) — elementwise VPU work, so
-    row/lane blocking cannot perturb bits."""
+    row/lane blocking cannot perturb bits.  The kernel reads the patches
+    tap-major (``[taps, M, C]``), so each tap slab is a whole
+    ``(bm, C)`` tile."""
     M, taps, C = patches3.shape
     pm, pc = (-M) % bm, (-C) % lane
-    x = patches3
+    x = jnp.swapaxes(patches3, 0, 1)
     if pm or pc:
-        x = jnp.pad(x, ((0, pm), (0, 0), (0, pc)))
+        x = jnp.pad(x, ((0, 0), (0, pm), (0, pc)))
     w = jnp.pad(wflat, ((0, 0), (0, pc))) if pc else wflat
     Mp, Cp = M + pm, C + pc
     if gated:
@@ -191,15 +200,16 @@ def spike_dwconv_pallas(patches3, wflat, *, gated: bool = True,
 
     y = pl.pallas_call(
         functools.partial(_dwconv_kernel, taps=taps),
-        grid=(Mp // bm,),
-        in_specs=[pl.BlockSpec((1, taps), lambda i: (i, 0)),
-                  pl.BlockSpec((bm, taps, Cp), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((taps, Cp), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bm, Cp), lambda i: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Mp // bm,),
+            in_specs=[pl.BlockSpec((taps, bm, Cp), lambda i, occ: (0, i, 0)),
+                      pl.BlockSpec((taps, Cp), lambda i, occ: (0, 0))],
+            out_specs=pl.BlockSpec((bm, Cp), lambda i, occ: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((bm, Cp), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((Mp, Cp), wflat.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, Cp), jnp.float32)],
         interpret=interpret,
-    )(occ, x, w)
+    )(occ.reshape(-1), x, w)
     return y[:M, :C]
 
 
@@ -226,9 +236,10 @@ def slab_occupancy_mask(x3, *, bm: int):
 def _conv_lif_kernel(occ_ref, x_ref, w_ref, scale_ref, bias_ref, s_ref,
                      acc_ref, u_ref, *, T: int, HW: int, k_steps: int,
                      bm: int, inline: bool, tau: float, v_th: float,
-                     v_reset: float, eps: float):
+                     v_reset: float, eps: float, precision):
     THW = T * HW
     n_rc = -(-THW // bm)
+    b = pl.program_id(0)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     for rc in range(n_rc):
         r0, r1 = rc * bm, min((rc + 1) * bm, THW)
@@ -241,13 +252,13 @@ def _conv_lif_kernel(occ_ref, x_ref, w_ref, scale_ref, bias_ref, s_ref,
                 # one-shot mask pass doesn't pay for itself)
                 cond = jnp.any(x_ref[0, r0:r1, c0:c1] != 0)
             else:
-                cond = occ_ref[0, rc, k] != 0
+                cond = occ_ref[(b * n_rc + rc) * k_steps + k] != 0
 
             @pl.when(cond)                 # activity gate per MAC tile
             def _mac(r0=r0, r1=r1, c0=c0, c1=c1):
                 acc_ref[r0:r1, :] += jnp.dot(
                     x_ref[0, r0:r1, c0:c1].astype(jnp.float32),
-                    w_ref[c0:c1, :].astype(jnp.float32),
+                    w_ref[c0:c1, :].astype(jnp.float32), precision=precision,
                     preferred_element_type=jnp.float32)
     # the conv output never leaves VMEM: run the exact shared epilogue
     # (instance-norm + affine + T-step LIF) on the resident accumulator
@@ -260,7 +271,7 @@ def _conv_lif_kernel(occ_ref, x_ref, w_ref, scale_ref, bias_ref, s_ref,
 def spike_conv_lif_pallas(patches, wmat, scale, bias, *, T: int, B: int,
                           HW: int, tau: float, v_th: float,
                           v_reset: float, eps: float, gate: str = "mask",
-                          bm: int = BM, interpret: bool = True):
+                          bm: int = BM, interpret: bool):
     """The fused spiking-conv layer: ``patches @ wmat`` + instance-norm
     + affine + T-step LIF in ONE kernel pass.
 
@@ -285,9 +296,9 @@ def spike_conv_lif_pallas(patches, wmat, scale, bias, *, T: int, B: int,
     surrogate-gradient custom VJP lives in
     ``repro.kernels.ops.spike_conv_lif_op``.
 
-    Interpret-mode shape note: slabs are left lane-unpadded (a compiled
-    Mosaic lowering would pad N/K to the 128-lane register file and
-    block HW, like ``norm_affine_lif_pallas``'s single-pass caveat).
+    Shape note: slabs are left lane-unpadded (Mosaic pads N/K to the
+    128-lane register file), and a program holds its whole slab, so
+    the kernel asks for ``VMEM_LIMIT_BYTES`` of scoped VMEM.
     """
     M, K = patches.shape
     N = wmat.shape[1]
@@ -309,16 +320,22 @@ def spike_conv_lif_pallas(patches, wmat, scale, bias, *, T: int, B: int,
     return pl.pallas_call(
         functools.partial(_conv_lif_kernel, T=T, HW=HW, k_steps=k_steps,
                           bm=bm, inline=(gate == "inline"), tau=tau,
-                          v_th=v_th, v_reset=v_reset, eps=eps),
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, n_rc, k_steps), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, T * HW, Kp), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((Kp, N), lambda b: (0, 0)),
-                  pl.BlockSpec((N,), lambda b: (0,)),
-                  pl.BlockSpec((N,), lambda b: (0,))],
-        out_specs=pl.BlockSpec((T, 1, HW, N), lambda b: (0, b, 0, 0)),
+                          v_th=v_th, v_reset=v_reset, eps=eps,
+                          precision=matmul_precision()),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, T * HW, Kp),
+                                   lambda b, occ: (b, 0, 0)),
+                      pl.BlockSpec((Kp, N), lambda b, occ: (0, 0)),
+                      pl.BlockSpec((N,), lambda b, occ: (0,)),
+                      pl.BlockSpec((N,), lambda b, occ: (0,))],
+            out_specs=pl.BlockSpec((T, 1, HW, N),
+                                   lambda b, occ: (0, b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((T * HW, N), jnp.float32),
+                            pltpu.VMEM((1, HW, N), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((T, B, HW, N), wmat.dtype),
-        scratch_shapes=[pltpu.VMEM((T * HW, N), jnp.float32),
-                        pltpu.VMEM((1, HW, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(occ, x3, w, scale, bias)
+    )(occ.reshape(-1), x3, w, scale, bias)

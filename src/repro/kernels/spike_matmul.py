@@ -26,10 +26,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocks import canonical_k_slices
+from repro.kernels.blocks import canonical_k_slices, matmul_precision
 
 
-def _kernel(x_ref, w_ref, y_ref, acc_ref, *, k_steps: int, bk: int):
+def _kernel(x_ref, w_ref, y_ref, acc_ref, *, k_steps: int, bk: int,
+            precision):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -43,6 +44,7 @@ def _kernel(x_ref, w_ref, y_ref, acc_ref, *, k_steps: int, bk: int):
         for c0, c1 in canonical_k_slices(bk):
             acc_ref[...] += jnp.dot(x[:, c0:c1].astype(jnp.float32),
                                     w_ref[c0:c1, :].astype(jnp.float32),
+                                    precision=precision,
                                     preferred_element_type=jnp.float32)
 
     @pl.when(k == k_steps - 1)
@@ -51,7 +53,7 @@ def _kernel(x_ref, w_ref, y_ref, acc_ref, *, k_steps: int, bk: int):
 
 
 def spike_matmul_pallas(x, w, *, bm: int = 128, bk: int = 128,
-                        bn: int = 128, interpret: bool = True):
+                        bn: int = 128, interpret: bool):
     """x: [M, K] (spikes), w: [K, N] -> [M, N].  Canonical-multiple
     ``bk`` (the tuner's swept space) is bit-exact vs the blocked jnp
     reference; other widths remain legal with a short tail slice."""
@@ -66,7 +68,8 @@ def spike_matmul_pallas(x, w, *, bm: int = 128, bk: int = 128,
     k_steps = Kp // bk
 
     y = pl.pallas_call(
-        functools.partial(_kernel, k_steps=k_steps, bk=bk),
+        functools.partial(_kernel, k_steps=k_steps, bk=bk,
+                          precision=matmul_precision()),
         grid=(Mp // bm, Np // bn, k_steps),
         in_specs=[pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
                   pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))],

@@ -54,6 +54,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 
 from repro.configs.base import TuneConfig
+from repro.kernels import blocks
 from repro.kernels.blocks import (CANONICAL_K_BLOCK, DEFAULT_BK, DEFAULT_BM,
                                   DEFAULT_BN, DEFAULT_LIF_BLOCK_N,
                                   validate_bk)
@@ -393,10 +394,13 @@ def _grid_steps(op: str, dims: Dict[str, int], cfg: LaunchConfig) -> int:
 
 
 def estimate(op: str, dims: Dict[str, int], cfg: LaunchConfig,
-             live: float = 1.0, interpret: bool = True) -> float:
+             live: float = 1.0, interpret: Optional[bool] = None) -> float:
     """Roofline launch estimate (seconds) used to RANK candidates —
     ``live`` is the measured live-tile fraction of the real inputs,
-    discounting gated FLOPs.  Only relative order matters."""
+    discounting gated FLOPs.  Only relative order matters.
+    ``interpret`` defaults to :func:`blocks.interpret_mode`."""
+    if interpret is None:
+        interpret = blocks.interpret_mode()
     gated = cfg.gate != "none"
     frac = live if gated else 1.0
     if op in ("spike_conv", "spike_matmul"):
@@ -445,12 +449,16 @@ def estimate(op: str, dims: Dict[str, int], cfg: LaunchConfig,
 # ---------------------------------------------------------------------------
 
 def measure(runner: Callable[[LaunchConfig], object], cfg: LaunchConfig,
-            reps: int) -> float:
+            reps: int, key: str) -> float:
     """Min-of-reps wall-clock (µs) of ``runner(cfg)`` after one warmup
-    call that absorbs trace/compile time; inf if the config fails."""
+    call that absorbs trace/compile time; inf if the config fails (the
+    op key and the exception are printed, so a compiler refusal during
+    a sweep is visible)."""
     try:
         jax.block_until_ready(runner(cfg))
-    except Exception:
+    except Exception as e:
+        print(f"tune: {key} candidate {cfg} failed: "
+              f"{type(e).__name__}: {e}")
         return float("inf")
     best = float("inf")
     for _ in range(max(1, reps)):
@@ -463,6 +471,7 @@ def measure(runner: Callable[[LaunchConfig], object], cfg: LaunchConfig,
 def _sweep(op: str, dims: Dict[str, int],
            runner: Callable[[LaunchConfig], object],
            tune_cfg: TuneConfig, live: float):
+    key = shape_key(op, **dims)
     cands = candidates(op, dims, tune_cfg)
     ranked = sorted(cands, key=lambda c: estimate(op, dims, c, live))
     short = ranked[:max(1, tune_cfg.prune_to)]
@@ -471,7 +480,7 @@ def _sweep(op: str, dims: Dict[str, int],
         short.append(dflt)          # the baseline is always measured
     best_cfg, best_us, default_us = dflt, float("inf"), float("inf")
     for c in short:
-        us = measure(runner, c, tune_cfg.reps)
+        us = measure(runner, c, tune_cfg.reps, key)
         if c == dflt:
             default_us = us
         if us < best_us:

@@ -2,16 +2,26 @@
 
 A FUNCTION, not a module-level constant, so importing this module never
 touches jax device state (device count is locked at first jax init).
+
+Every mesh here has ``Auto`` axes: ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which ``with_sharding_constraint`` becomes an
+assertion and reshapes that merge a sharded dim raise.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_serving_mesh(batch: int = None, max_devices: int = None):
@@ -28,7 +38,7 @@ def make_serving_mesh(batch: int = None, max_devices: int = None):
             n -= 1
     if n <= 1:
         return None
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def make_mesh_for(devices: int, model_parallel: int = None):
@@ -37,5 +47,5 @@ def make_mesh_for(devices: int, model_parallel: int = None):
     model_parallel = model_parallel or min(16, devices)
     while devices % model_parallel:
         model_parallel //= 2
-    return jax.make_mesh((devices // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _auto_mesh((devices // model_parallel, model_parallel),
+                      ("data", "model"))

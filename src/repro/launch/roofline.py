@@ -93,11 +93,14 @@ def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
 INTERPRET_STEP_OVERHEAD_S = 50e-6
 COMPILED_STEP_OVERHEAD_S = 2e-6
 
-# Per-core VMEM capacity (TPU v5e: 128 MB/chip across cores; we budget a
-# conservative 16 MB per kernel program so double-buffered pipelining and
-# the compiler's own spills still fit).  The backbone fusion planner
-# (``repro.kernels.backbone_fuse.plan_segments``) forces a segment
-# boundary when a fused run's per-batch working set would exceed this.
+# VMEM (TPU v5e: 128 MiB per TensorCore; Mosaic's default scoped limit
+# per kernel is 16 MiB).  Kernels whose working set outgrows the default
+# request ``VMEM_LIMIT_BYTES`` through their compiler params.  The
+# backbone fusion planner (``repro.kernels.backbone_fuse.plan_segments``)
+# forces a segment boundary when a fused run's per-batch working set
+# would exceed ``VMEM_BYTES`` — a quarter of that limit, because its
+# estimate counts neither double-buffered blocks nor lane padding.
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
 VMEM_BYTES = 16 * 2 ** 20
 F32_BYTES = 4
 

@@ -20,21 +20,25 @@ Sharding: pass a 1-D ``("data",)`` mesh (see
 NPU params once at construction and uploads every slot pytree with the
 batch dimension partitioned over the data axis
 (``repro.distributed.sharding.batch_sharding``).  The tick math is
-batch-parallel (per-slot instance norms, vmapped ISP), so XLA runs it
-SPMD with no resharding; only the batch-reduced sparsity telemetry
-crosses devices (an all-reduce).  ``mesh=None`` degrades to the
-single-device path, bit-for-bit the pre-split engine.
+batch-parallel (per-slot instance norms, vmapped ISP), so the tick body
+runs once per shard under ``shard_map`` with no resharding; only the
+batch-reduced sparsity telemetry crosses devices (an all-reduce).
+``mesh=None`` degrades to the single-device path, bit-for-bit the
+pre-split engine.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import EncodingConfig, ISPConfig, SNNConfig
 from repro.core.encoding import EventStream, events_to_voxel_batch
-from repro.core.npu import npu_forward
+from repro.core.npu import NPUOutput, npu_forward
 from repro.distributed.sharding import (MeshAxes, batch_sharding,
                                         from_mesh, replicated_sharding)
 from repro.isp.pipeline import (control_vector_pipeline,
@@ -42,6 +46,17 @@ from repro.isp.pipeline import (control_vector_pipeline,
 from repro.isp.stages import BACKENDS as ISP_BACKENDS
 from repro.isp.stages import control_to_stage_params
 from repro.kernels import tune
+from repro.kernels.blocks import matmul_precision
+
+
+def _precision_scope():
+    """One matmul precision for the whole tick, whichever backend: the
+    kernels ask for the same one (``blocks.matmul_precision``), so every
+    rung of the fleet's fallback ladder computes the same numbers on a
+    TPU, whose XLA default is one bf16 pass.  Entered at trace time."""
+    prec = matmul_precision()
+    return (contextlib.nullcontext() if prec is None
+            else jax.default_matmul_precision(prec))
 
 
 class EngineCore:
@@ -152,7 +167,7 @@ class EngineCore:
             # body runs at TRACE time only; ``pinned`` makes every op
             # dispatch inside resolve against the construction-time
             # table snapshot (zero per-tick resolution cost)
-            with tune.pinned(self._tune_table):
+            with tune.pinned(self._tune_table), _precision_scope():
                 # encode stage: voxelize the event slots inside the same
                 # executable (slots submitted as voxels keep their
                 # buffer); traced out entirely for non-DVS layouts
@@ -178,7 +193,37 @@ class EngineCore:
         # steady-state serving holds one device copy of the slot state,
         # not two.  (On backends without donation support this is a
         # no-op warning, never an error.)
-        self._step = jax.jit(_step, donate_argnums=(1, 2, 3, 4))
+        step = _step if self.ax.mesh is None else self._per_shard(_step)
+        self._step = jax.jit(step, donate_argnums=(1, 2, 3, 4))
+
+    def _per_shard(self, step):
+        """Run the tick body once per data shard (``shard_map``): XLA's
+        SPMD partitioner cannot split a Mosaic kernel, and the tick is
+        batch-parallel anyway — each device serves its own slots, and
+        only the batch-reduced telemetry crosses devices, as a mean of
+        the per-shard values."""
+        d = self.ax.dp_spec
+        shard_b = P(d)
+        slot_specs = (P(None, d), shard_b, EventStream(*(shard_b,) * 5),
+                      shard_b)
+        out_specs = (NPUOutput(raw_pred=shard_b, control=shard_b,
+                               sparsity=P(), tile_skip=P(),
+                               layer_rates=P()),
+                     shard_b, shard_b)
+
+        def body(params, *slots):
+            out, rgb, sp = step(params, *slots)
+            mean = functools.partial(jax.lax.pmean, axis_name=d)
+            rates = out.layer_rates
+            out = out._replace(
+                sparsity=mean(out.sparsity), tile_skip=mean(out.tile_skip),
+                layer_rates=None if rates is None
+                else {k: mean(v) for k, v in rates.items()})
+            return out, rgb, sp
+
+        return jax.shard_map(body, mesh=self.ax.mesh,
+                             in_specs=(P(),) + slot_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # ------------------------------------------------------------------
     def upload(self, slots):

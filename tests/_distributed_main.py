@@ -9,6 +9,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs.registry import reduced
 from repro.distributed.sharding import MeshAxes, from_mesh
@@ -17,6 +18,8 @@ from repro.models.lm import lm_loss, serve_decode
 from repro.optim.adamw import AdamWConfig
 from repro.train.state import init_train_state
 from repro.train.step import make_train_step
+
+AUTO = AxisType.Auto
 
 
 def _mesh_context(mesh):
@@ -30,7 +33,7 @@ def _mesh_context(mesh):
 def check_sharded_loss_matches_local():
     """pjit on a (2 data, 4 model) mesh == single-device math, incl. the
     shard_map MoE and the ZeRO param shardings."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
     ax = from_mesh(mesh)
     local = MeshAxes()
     for arch in ("qwen2-7b", "arctic-480b", "jamba-v0.1-52b"):
@@ -58,7 +61,7 @@ def check_sharded_loss_matches_local():
 
 def check_sharded_decode_matches_local():
     """Sequence-sharded flash-decode (shard_map LSE merge) == local."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
     ax = from_mesh(mesh)
     local = MeshAxes()
     for arch in ("qwen2-7b", "deepseek-v3-671b"):
@@ -86,7 +89,7 @@ def check_sharded_decode_matches_local():
 
 
 def check_sharded_train_step_runs():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
     ax = from_mesh(mesh)
     cfg = reduced("qwen2-7b")
     opt = AdamWConfig(lr=1e-3)
@@ -116,7 +119,7 @@ def check_manual_dp_compression_step():
     from repro.distributed.compress import make_manual_dp_train_step
     from repro.optim.adamw import adamw_init, adamw_update
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AUTO, AUTO))
     ax = MeshAxes(mesh=mesh, dp=("pod", "data"), tp=None)
     cfg = reduced_snn("spiking_yolo")
     opt = AdamWConfig(lr=2e-3)
@@ -196,7 +199,7 @@ def check_detector_dp_matches_local():
 def check_pipeline_parallel():
     from repro.distributed.pipeline_parallel import (bubble_fraction,
                                                      pipeline_forward)
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = jax.make_mesh((4,), ("pipe",), axis_types=(AUTO,))
     rng = np.random.default_rng(0)
     S, M, mb, d = 4, 8, 2, 16
     Ws = jnp.asarray(rng.normal(0, 0.3, (S, d, d)).astype(np.float32))

@@ -13,6 +13,7 @@ def test_distributed_integration():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src")
+    env["JAX_PLATFORMS"] = "cpu"    # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(root, "tests", "_distributed_main.py")],

@@ -169,7 +169,7 @@ def test_invalid_args_rejected():
     with pytest.raises(ValueError, match="mode"):
         event_voxel_pallas(ev.t, ev.x, ev.y, ev.p,
                            ev.valid.astype(jnp.int32), time_steps=T,
-                           height=H, width=W, mode="typo")
+                           height=H, width=W, mode="typo", interpret=True)
     with pytest.raises(ValueError, match="oob"):
         events_to_voxel(jax.tree_util.tree_map(lambda a: a[0], ev),
                         time_steps=T, height=H, width=W, oob="typo")

@@ -253,7 +253,8 @@ def test_fleet_stats_percentiles(setup):
 
 def test_fleet_rejects_batch_not_divisible_by_mesh(setup):
     cfg, params = setup
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     # 1 device always divides; the divisibility guard itself is covered
     # in the 8-device subprocess — here just check explicit mesh wiring
     fleet = FleetEngine(params, cfg, mesh=mesh,
@@ -272,6 +273,7 @@ def test_fleet_sharded_integration():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src")
+    env["JAX_PLATFORMS"] = "cpu"    # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(root, "tests", "_fleet_main.py")],

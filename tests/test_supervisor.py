@@ -3,6 +3,7 @@ outcomes (pure unit, fake clock), fallback-ladder output parity, NaN
 quarantine through the real fleet, degradation + recovery end to end,
 and hedged re-dispatch."""
 import dataclasses
+import re
 
 import jax
 import numpy as np
@@ -13,11 +14,14 @@ from repro.configs.registry import reduced_snn
 from repro.core.encoding import voxel_batch
 from repro.core.npu import init_npu
 from repro.data.synthetic import make_scene_batch
+from repro.kernels import blocks
 from repro.serve.cognitive_engine import PerceptionRequest
+from repro.serve.engine_core import EngineCore
 from repro.serve.faults import FaultEvent, FaultKind, FaultPlan
 from repro.serve.fleet import FleetEngine
 from repro.serve.scheduler import RequestStatus
 from repro.serve.supervisor import BreakerState, FleetSupervisor
+from repro.serve.transport import StagingBank
 
 
 @pytest.fixture(scope="module")
@@ -191,6 +195,23 @@ def test_ladder_rungs_bit_parity(setup):
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(rgb), np.asarray(ref_rgb),
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_jnp_rung_takes_the_kernels_matmul_precision(setup, monkeypatch):
+    """Decided as on a TPU, every dot and conv of the jnp rung's tick asks
+    for the precision the kernels' own dots use (full f32), so the
+    ladder's rungs agree when compiled rather than the jnp rung falling
+    to XLA's one-pass bf16 default."""
+    cfg, params = setup
+    monkeypatch.setattr(blocks, "interpret_mode", lambda: False)
+    assert blocks.matmul_precision() == "highest"
+    core = EngineCore(params, dataclasses.replace(cfg, backend="jnp"),
+                      batch=2)
+    bank = StagingBank(core.cfg, core.batch, core.frame_hw,
+                       core.enc_cfg.event_capacity)
+    text = core._step.lower(core.params, *bank.as_tuple()).as_text()
+    dots = re.findall(r"stablehlo\.(?:dot_general|convolution)\b.*", text)
+    assert dots and all("HIGHEST" in d for d in dots), dots
 
 
 # ---------------------------------------------------------------------------

@@ -107,7 +107,6 @@ def run(emit):
                                               bayer=payloads[0][1]))
         fleet.drain()
         assert warm.status is RequestStatus.DONE
-        fleet._latencies.clear()
         fleet.n_delivered = 0
 
         delivered, wall = _drive_closed_loop(fleet, payloads, rounds)

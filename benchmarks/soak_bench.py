@@ -40,6 +40,7 @@ from repro.core.npu import init_npu
 from repro.serve.cognitive_engine import PerceptionRequest
 from repro.serve.faults import FaultPlan, make_malformed_request
 from repro.serve.fleet import FleetEngine
+from repro.serve.scheduler import RequestStatus
 
 BATCH = 8
 OFFERED_PER_TICK = 8          # offered load = tick capacity (open loop)
@@ -57,7 +58,8 @@ def _payloads(cfg, n=32):
 
 
 def _soak(params, cfg, fault_name: str, n_ticks: int):
-    """One soak arm; returns (fleet, wall_s)."""
+    """One soak arm; returns (fleet, wall_s, sorted latencies in s of
+    the delivered requests)."""
     fault_cfg = get_fault_config(fault_name)
     plan = FaultPlan.from_config(fault_cfg, n_ticks + 8, BATCH) \
         if fault_name != "none" else None
@@ -73,6 +75,7 @@ def _soak(params, cfg, fault_name: str, n_ticks: int):
     fleet._prewarm()
 
     rid = 0
+    finished = []
     t0 = time.perf_counter()
     for tick in range(n_ticks):
         for _ in range(OFFERED_PER_TICK):
@@ -82,9 +85,17 @@ def _soak(params, cfg, fault_name: str, n_ticks: int):
         if plan is not None and plan.malformed_at(tick):
             fleet.submit(make_malformed_request(rid))
             rid += 1
-        fleet.step()
-    fleet.drain()
-    return fleet, time.perf_counter() - t0
+        finished.extend(fleet.step())
+    finished.extend(fleet.drain())
+    wall = time.perf_counter() - t0
+    lat = sorted(r.telemetry.latency_s for r in finished
+                 if r.status is RequestStatus.DONE)
+    return fleet, wall, lat
+
+
+def _pct(lat, p):
+    return lat[min(len(lat) - 1, int(p * len(lat)))] if lat \
+        else float("nan")
 
 
 def run(emit):
@@ -92,16 +103,16 @@ def run(emit):
     cfg = reduced_snn("spiking_yolo", backend="pallas")
     params = init_npu(jax.random.PRNGKey(1), cfg)
     for arm in ("none", "chaos"):
-        fleet, wall = _soak(params, cfg, arm, n_ticks)
+        fleet, wall, lat = _soak(params, cfg, arm, n_ticks)
         s = fleet.stats()
         sup = s["supervisor"]
         label = "nofault" if arm == "none" else "chaos"
         ndev = s["n_devices"]
         tag = (f"avail{s['availability']:.4f}_nan{s['nan_delivered']}"
                f"_batch{BATCH}_ndev{ndev}")
-        emit(f"soak_latency_p50_{label}", s["latency_p50_s"] * 1e6, tag)
-        emit(f"soak_latency_p99_{label}", s["latency_p99_s"] * 1e6, tag)
-        emit(f"soak_latency_p999_{label}", s["latency_p999_s"] * 1e6, tag)
+        emit(f"soak_latency_p50_{label}", _pct(lat, 0.50) * 1e6, tag)
+        emit(f"soak_latency_p99_{label}", _pct(lat, 0.99) * 1e6, tag)
+        emit(f"soak_latency_p999_{label}", _pct(lat, 0.999) * 1e6, tag)
         transitions = sup["transitions"]
         demotes = sum(e["event"] == "demote" for e in transitions)
         promotes = sum(e["event"] == "promote" for e in transitions)

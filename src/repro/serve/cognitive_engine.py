@@ -186,7 +186,7 @@ class CognitiveEngine:
         # ONE host->device upload of the whole staging bank, ONE step
         # launch, ONE batched device->host fetch (EngineCore.tick);
         # per-request results below are numpy views into the fetch
-        out, rgb, sp = self.core.tick(self.staging.as_tuple())
+        out, rgb, sp = self.core.tick(self.staging.as_tuple(), self.ticks)
         self.last_tick_s = time.perf_counter() - t0
         self.ticks += 1
         # batch-level sparsity telemetry (one dict per tick, shared by

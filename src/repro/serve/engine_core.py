@@ -22,9 +22,17 @@ batch dimension partitioned over the data axis
 (``repro.distributed.sharding.batch_sharding``).  The tick math is
 batch-parallel (per-slot instance norms, vmapped ISP), so the tick body
 runs once per shard under ``shard_map`` with no resharding; only the
-batch-reduced sparsity telemetry crosses devices (an all-reduce).
+optional per-layer firing rates cross devices (an all-reduce).
 ``mesh=None`` degrades to the single-device path, bit-for-bit the
 pre-split engine.
+
+Tracing: ``upload`` and ``dispatch`` open the ``serve.upload`` and
+``serve.dispatch`` profiler spans, and a caller opens ``fetch_span``,
+``serve.fetch``, around ``fetch``: each carries the caller's ``tick``,
+the transfers their ``bytes``.  The tick body's operations carry the
+named scopes ``encode``, ``npu``, ``control`` and ``isp`` in their
+``op_name`` metadata.  Both land in a ``jax.profiler`` trace on one
+clock; with no profiler running a span costs its enabled-check.
 """
 from __future__ import annotations
 
@@ -34,6 +42,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import EncodingConfig, ISPConfig, SNNConfig
@@ -47,6 +56,7 @@ from repro.isp.stages import BACKENDS as ISP_BACKENDS
 from repro.isp.stages import control_to_stage_params
 from repro.kernels import tune
 from repro.kernels.blocks import matmul_precision
+from repro.serve.transport import bank_nbytes
 
 
 def _precision_scope():
@@ -89,6 +99,12 @@ class EngineCore:
         self.batch = batch
         self.frame_hw: Tuple[int, int] = (
             frame_hw if frame_hw is not None else (cfg.height, cfg.width))
+        # bytes each transfer moves, for the spans: the staging bank's
+        # now; the tick's outputs once the first dispatch has their
+        # shapes
+        self.upload_bytes = bank_nbytes(cfg, batch, self.frame_hw,
+                                        self.enc_cfg.event_capacity)
+        self.fetch_bytes: Optional[int] = None
 
         if control_order not in ("pipeline", "legacy"):
             raise ValueError(f"control_order must be 'pipeline' or "
@@ -172,18 +188,28 @@ class EngineCore:
                 # executable (slots submitted as voxels keep their
                 # buffer); traced out entirely for non-DVS layouts
                 if ncfg.in_channels == 2:
-                    enc = _encode(events)
-                    voxels = jnp.where(
-                        from_events[None, :, None, None, None], enc, voxels)
-                out = npu_forward(params, voxels, ncfg,
-                                  collect_sparsity=collect)
-                ctrl = out.control[:, perm] if perm is not None \
-                    else out.control[:, :nd]
-                rgb = jax.vmap(
-                    lambda r, c: control_vector_pipeline(r, c, icfg))(
-                        bayer, ctrl)
-                sp = jax.vmap(
-                    lambda c: control_to_stage_params(c, icfg.stages))(ctrl)
+                    with jax.named_scope("encode"):
+                        enc = _encode(events)
+                        voxels = jnp.where(
+                            from_events[None, :, None, None, None], enc,
+                            voxels)
+                with jax.named_scope("npu"):
+                    out = npu_forward(params, voxels, ncfg,
+                                      collect_sparsity=collect)
+                # the batch-level sparsity scalars serve training only:
+                # dropped here, so the tick neither reduces nor fetches
+                # them
+                out = out._replace(sparsity=None, tile_skip=None)
+                with jax.named_scope("control"):
+                    ctrl = out.control[:, perm] if perm is not None \
+                        else out.control[:, :nd]
+                with jax.named_scope("isp"):
+                    rgb = jax.vmap(
+                        lambda r, c: control_vector_pipeline(r, c, icfg))(
+                            bayer, ctrl)
+                    sp = jax.vmap(
+                        lambda c: control_to_stage_params(c, icfg.stages))(
+                            ctrl)
                 return out, rgb, sp
 
         # one executable serves every tick / control setting / ingestion
@@ -200,25 +226,24 @@ class EngineCore:
         """Run the tick body once per data shard (``shard_map``): XLA's
         SPMD partitioner cannot split a Mosaic kernel, and the tick is
         batch-parallel anyway — each device serves its own slots, and
-        only the batch-reduced telemetry crosses devices, as a mean of
-        the per-shard values."""
+        only the optional per-layer firing rates cross devices, as a
+        mean of the per-shard values."""
         d = self.ax.dp_spec
         shard_b = P(d)
         slot_specs = (P(None, d), shard_b, EventStream(*(shard_b,) * 5),
                       shard_b)
         out_specs = (NPUOutput(raw_pred=shard_b, control=shard_b,
-                               sparsity=P(), tile_skip=P(),
+                               sparsity=None, tile_skip=None,
                                layer_rates=P()),
                      shard_b, shard_b)
 
         def body(params, *slots):
             out, rgb, sp = step(params, *slots)
-            mean = functools.partial(jax.lax.pmean, axis_name=d)
             rates = out.layer_rates
-            out = out._replace(
-                sparsity=mean(out.sparsity), tile_skip=mean(out.tile_skip),
-                layer_rates=None if rates is None
-                else {k: mean(v) for k, v in rates.items()})
+            if rates is not None:
+                mean = functools.partial(jax.lax.pmean, axis_name=d)
+                out = out._replace(
+                    layer_rates={k: mean(v) for k, v in rates.items()})
             return out, rgb, sp
 
         return jax.shard_map(body, mesh=self.ax.mesh,
@@ -226,29 +251,48 @@ class EngineCore:
                              out_specs=out_specs, check_vma=False)
 
     # ------------------------------------------------------------------
-    def upload(self, slots):
+    # ``tick`` is the caller's number for the tick, carried by the span
+    def upload(self, slots, tick: int = -1):
         """ONE host->device transfer of a whole staging bank
         ``(voxels, bayer, events, from_events)``; partitioned over the
         mesh's data axis when sharded.  Returns device buffers ready to
         be donated to :meth:`dispatch`."""
-        if self._slot_shardings is None:
-            return jax.device_put(slots)
-        return jax.device_put(slots, self._slot_shardings)
+        with TraceAnnotation("serve.upload", tick=tick,
+                             bytes=self.upload_bytes):
+            if self._slot_shardings is None:
+                return jax.device_put(slots)
+            return jax.device_put(slots, self._slot_shardings)
 
-    def dispatch(self, slots_dev):
+    def dispatch(self, slots_dev, tick: int = -1):
         """Launch the tick executable on uploaded slot buffers.  JAX
         dispatch is asynchronous: this returns futures immediately, so a
         caller may upload the NEXT bank while this tick computes (the
         double-buffer overlap ``repro.serve.fleet`` exploits)."""
         voxels, bayer, events, from_events = slots_dev
-        return self._step(self.params, voxels, bayer, events, from_events)
+        with TraceAnnotation("serve.dispatch", tick=tick):
+            outputs = self._step(self.params, voxels, bayer, events,
+                                 from_events)
+        if self.fetch_bytes is None:        # the outputs' shapes, once
+            self.fetch_bytes = sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(outputs))
+        return outputs
 
     def fetch(self, outputs):
         """ONE batched device->host gather of the tick's output pytree
-        (blocks until the compute lands)."""
+        (blocks until the compute lands).  Its span is the caller's
+        (:meth:`fetch_span`): ``fetch`` keeps the one argument that
+        wrappers of it, such as the fault injector's, pass on."""
         return jax.device_get(outputs)
 
-    def tick(self, slots):
+    def fetch_span(self, tick: int = -1):
+        """The ``serve.fetch`` span a caller opens around :meth:`fetch`
+        of tick ``tick``."""
+        return TraceAnnotation("serve.fetch", tick=tick,
+                               bytes=self.fetch_bytes or 0)
+
+    def tick(self, slots, tick: int = -1):
         """upload -> dispatch -> fetch in one call (the unpipelined
         path ``CognitiveEngine`` uses)."""
-        return self.fetch(self.dispatch(self.upload(slots)))
+        outputs = self.dispatch(self.upload(slots, tick), tick)
+        with self.fetch_span(tick):
+            return self.fetch(outputs)

@@ -159,19 +159,19 @@ class FaultInjector:
         return getattr(self._core, name)
 
     # -- intercepted boundary ------------------------------------------
-    def upload(self, slots):
-        tick = self._ticker.tick
-        for ev in self._plan.events_at(tick):
+    def upload(self, slots, tick: int = -1):
+        fault_tick = self._ticker.tick
+        for ev in self._plan.events_at(fault_tick):
             if ev.kind is FaultKind.CORRUPT_INPUT:
                 voxels = slots[0]
                 voxels[:, ev.slot % voxels.shape[1]] = ev.value
                 self.n_injected += 1
-        return self._core.upload(slots)
+        return self._core.upload(slots, tick)
 
-    def dispatch(self, slots_dev):
-        tick = self._ticker.tick
+    def dispatch(self, slots_dev, tick: int = -1):
+        fault_tick = self._ticker.tick
         self._ticker.tick += 1
-        return (tick, self._core.dispatch(slots_dev))
+        return (fault_tick, self._core.dispatch(slots_dev, tick))
 
     def fetch(self, outputs):
         tick, real = outputs

@@ -45,9 +45,21 @@ worse than a late one, a late one worse than a shed one):
   climb back up after recovery.
 
 Every delivered ``PerceptionResult`` carries a
-``scheduler.RequestTelemetry`` (timestamps + retry/hedge/quarantine/
-rung accounting); ``stats()`` reduces them to the p50/p99/p99.9
-latency + availability envelope ``benchmarks/soak_bench.py`` reports.
+``scheduler.RequestTelemetry`` (timestamps, the serving tick, and
+retry/hedge/quarantine/rung accounting); callers reduce their own
+requests' telemetry to latency percentiles, and ``stats()`` keeps the
+fleet's counters and availability.
+
+Tracing: each ``step()`` opens ``jax.profiler.TraceAnnotation`` spans,
+one per stage and never one per slot or request: ``serve.step`` (arg
+``tick``, the number of the tick this step would dispatch) around
+``serve.shed`` (shedding and hedging), ``serve.pack`` (args ``slots``,
+the requests packed, and ``queued``, the queue left), the engine core's
+``serve.upload``, ``serve.dispatch`` and ``serve.fetch``, and
+``serve.deliver`` (the health checks and hand-off of the harvested
+tick's ``slots`` requests).  Every span carries the ``tick`` it works
+for, the number each delivered request records as
+``RequestTelemetry.tick``.
 """
 from __future__ import annotations
 
@@ -57,6 +69,7 @@ from typing import Callable, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import (EncodingConfig, FleetConfig, ISPConfig,
                                 SNNConfig, SupervisorConfig)
@@ -170,7 +183,6 @@ class FleetEngine:
         self._inflight: Optional[_Inflight] = None
         self.ticks = 0
         self.last_tick_s = 0.0
-        self._latencies: List[float] = []   # delivered-request latency_s
         self.n_delivered = 0
         self.n_deadline_missed = 0
         self.n_failed = 0                   # terminal FAILED requests
@@ -233,14 +245,40 @@ class FleetEngine:
         t0 = time.perf_counter()
         now = self.clock()
         terminal: List[ServeRequest] = []
-        for sreq in self.queue.shed_expired(now):
-            if sreq.is_hedge:               # client never sees the copy
-                self._settle_dead_hedge(sreq, terminal)
-                continue
-            terminal.append(sreq)
-        self._maybe_hedge(now)
+        tick = self.ticks
+        with TraceAnnotation("serve.step", tick=tick):
+            with TraceAnnotation("serve.shed", tick=tick):
+                for sreq in self.queue.shed_expired(now):
+                    if sreq.is_hedge:       # client never sees the copy
+                        self._settle_dead_hedge(sreq, terminal)
+                        continue
+                    terminal.append(sreq)
+                self._maybe_hedge(now)
+            with TraceAnnotation("serve.pack", tick=tick) as span:
+                packed = self._pack(now, terminal)
+                span.set_metadata(slots=len(packed), queued=len(self.queue))
+            # dispatch the new tick BEFORE blocking on the old one: the
+            # upload + launch are queued asynchronously, so the H2D copy
+            # of tick N+1 overlaps tick N's compute
+            new_inflight = (self._dispatch(packed, terminal) if packed
+                            else None)
+            # harvest: block on the PREVIOUS tick's results (pipeline
+            # depth 2 with double buffering; without it, harvest this
+            # very tick)
+            if self.fleet_cfg.double_buffer:
+                harvest, self._inflight = self._inflight, new_inflight
+            else:
+                harvest, self._inflight = new_inflight, None
+            if harvest is not None:
+                self._harvest(harvest, terminal)
+        self.last_tick_s = time.perf_counter() - t0
+        return terminal
 
-        # pack: continuous batching fills every slot the queue can
+    def _pack(self, now: float,
+              terminal: List[ServeRequest]) -> List[Tuple[int, ServeRequest]]:
+        """Continuous batching: fill every slot of the front staging
+        bank the queue can; returns the packed ``(slot, request)``
+        pairs."""
         bank = self.buffers.front
         packed: List[Tuple[int, ServeRequest]] = []
         while len(packed) < self.batch and len(self.queue):
@@ -275,47 +313,38 @@ class FleetEngine:
             packed.append((slot, sreq))
         for slot in range(len(packed), self.batch):
             bank.from_events[slot] = False  # recycled slots stay inert
+        return packed
 
-        # dispatch the new tick BEFORE blocking on the old one: the
-        # upload + launch are queued asynchronously, so the H2D copy of
-        # tick N+1 overlaps tick N's compute
-        new_inflight = None
-        if packed:
-            rung = (self.supervisor.select_rung(self.ticks)
-                    if self.supervisor is not None else 0)
-            core = self.cores[rung]
-            try:
-                dev = core.upload(bank.as_tuple())   # ONE device_put
-                outputs = core.dispatch(dev)         # async launch
-            except TransientTickError as e:
-                t_fail = self.clock()
-                if self.supervisor is not None:
-                    self.supervisor.record_tick(self.ticks, rung, False,
-                                                0.0, f"dispatch: {e}")
-                for _, sreq in packed:
-                    self._fail(sreq, str(e), retryable=True, now=t_fail,
-                               terminal=terminal)
-            else:
-                t_disp = self.clock()
-                for _, sreq in packed:
-                    sreq.status = RequestStatus.IN_FLIGHT
-                    sreq.telemetry.t_dispatch = t_disp
-                new_inflight = _Inflight(
-                    packed, outputs, core, rung,
-                    self.ladder_names[rung], self.ticks, t_disp)
-                self.buffers.flip()
-                self.ticks += 1
-
-        # harvest: block on the PREVIOUS tick's results (pipeline depth
-        # 2 with double buffering; without it, harvest this very tick)
-        if self.fleet_cfg.double_buffer:
-            harvest, self._inflight = self._inflight, new_inflight
-        else:
-            harvest, self._inflight = new_inflight, None
-        if harvest is not None:
-            self._harvest(harvest, terminal)
-        self.last_tick_s = time.perf_counter() - t0
-        return terminal
+    def _dispatch(self, packed: List[Tuple[int, ServeRequest]],
+                  terminal: List[ServeRequest]) -> Optional[_Inflight]:
+        """Upload the front bank and launch its tick on the
+        supervisor-selected rung; None when the launch failed."""
+        tick = self.ticks
+        rung = (self.supervisor.select_rung(tick)
+                if self.supervisor is not None else 0)
+        core = self.cores[rung]
+        try:
+            # ONE device_put, then the asynchronous launch
+            dev = core.upload(self.buffers.front.as_tuple(), tick)
+            outputs = core.dispatch(dev, tick)
+        except TransientTickError as e:
+            t_fail = self.clock()
+            if self.supervisor is not None:
+                self.supervisor.record_tick(tick, rung, False, 0.0,
+                                            f"dispatch: {e}")
+            for _, sreq in packed:
+                self._fail(sreq, str(e), retryable=True, now=t_fail,
+                           terminal=terminal)
+            return None
+        t_disp = self.clock()
+        for _, sreq in packed:
+            sreq.status = RequestStatus.IN_FLIGHT
+            sreq.telemetry.t_dispatch = t_disp
+            sreq.telemetry.tick = tick
+        self.buffers.flip()
+        self.ticks += 1
+        return _Inflight(packed, outputs, core, rung,
+                         self.ladder_names[rung], tick, t_disp)
 
     # ------------------------------------------------------------------
     # failure handling + resilience
@@ -410,7 +439,8 @@ class FleetEngine:
                  terminal: List[ServeRequest]) -> None:
         sup = self.supervisor
         try:
-            out, rgb, sp = inflight.core.fetch(inflight.outputs)
+            with inflight.core.fetch_span(inflight.tick_no):
+                out, rgb, sp = inflight.core.fetch(inflight.outputs)
         except TransientTickError as e:
             now = self.clock()
             if sup is not None:
@@ -428,22 +458,25 @@ class FleetEngine:
             spars = {k: float(v) for k, v in out.layer_rates.items()}
         ok, reason = True, ""
         guard = sup is not None and sup.cfg.nan_guard
-        for slot, sreq in inflight.packed:
-            finite = bool(np.isfinite(np.asarray(rgb[slot])).all()
-                          and np.isfinite(np.asarray(out.control[slot])).all()
-                          and np.isfinite(np.asarray(out.raw_pred[slot])).all())
-            if guard and not finite:
-                # quarantine: a non-finite result is NEVER delivered
-                ok, reason = False, "nan_output"
-                sup.n_quarantined += 1
-                sreq.telemetry.quarantined = True
-                self._fail(sreq, "non-finite result quarantined",
-                           retryable=True, now=now, terminal=terminal)
-                continue
-            if not finite:
-                self.n_nan_delivered += 1   # unsupervised: count the leak
-            self._deliver_one(sreq, slot, out, rgb, sp, spars, now,
-                              inflight, terminal)
+        with TraceAnnotation("serve.deliver", tick=inflight.tick_no,
+                             slots=len(inflight.packed)):
+            for slot, sreq in inflight.packed:
+                finite = bool(
+                    np.isfinite(np.asarray(rgb[slot])).all()
+                    and np.isfinite(np.asarray(out.control[slot])).all()
+                    and np.isfinite(np.asarray(out.raw_pred[slot])).all())
+                if guard and not finite:
+                    # quarantine: a non-finite result is NEVER delivered
+                    ok, reason = False, "nan_output"
+                    sup.n_quarantined += 1
+                    sreq.telemetry.quarantined = True
+                    self._fail(sreq, "non-finite result quarantined",
+                               retryable=True, now=now, terminal=terminal)
+                    continue
+                if not finite:
+                    self.n_nan_delivered += 1   # unsupervised: the leak
+                self._deliver_one(sreq, slot, out, rgb, sp, spars, now,
+                                  inflight, terminal)
         if sup is not None:
             dl = sup.cfg.tick_deadline_ms
             if ok and dl is not None and wall * 1e3 > dl:
@@ -464,6 +497,7 @@ class FleetEngine:
         tel.rung = inflight.rung_name
         if sreq.is_hedge:
             tel.hedge_won = True
+            tel.tick = inflight.tick_no     # the tick that delivered
             self.n_hedge_wins += 1
             sreq.status = RequestStatus.DONE
         primary.request.result = PerceptionResult(
@@ -473,7 +507,6 @@ class FleetEngine:
                 lambda x, s=slot: x[s], sp),
             sparsity=spars, telemetry=tel)
         primary.status = RequestStatus.DONE
-        self._latencies.append(tel.latency_s)
         self.n_delivered += 1
         self.n_deadline_missed += bool(tel.deadline_missed)
         terminal.append(primary)
@@ -518,16 +551,10 @@ class FleetEngine:
     # telemetry
     # ------------------------------------------------------------------
     def stats(self) -> dict:
-        """Serving envelope over every delivered request: p50/p99/p99.9
-        latency (seconds), availability, and counters for shed/
-        rejected/failed/retried/hedged work; supervisor state rides
-        along when supervision is enabled."""
-        lat = sorted(self._latencies)
-        n = len(lat)
-
-        def pct(p):
-            return lat[min(n - 1, int(p * n))] if n else float("nan")
-
+        """Serving counters: availability and shed/rejected/failed/
+        retried/hedged work; supervisor state rides along when
+        supervision is enabled.  Latency percentiles are the caller's,
+        over its delivered requests' ``RequestTelemetry``."""
         terminal = (self.n_delivered + self.n_failed
                     + self.queue.n_expired)
         out = {
@@ -545,9 +572,6 @@ class FleetEngine:
             else float("nan"),
             "ticks": self.ticks,
             "n_devices": self.core.n_devices,
-            "latency_p50_s": pct(0.50),
-            "latency_p99_s": pct(0.99),
-            "latency_p999_s": pct(0.999),
         }
         if self.supervisor is not None:
             out["supervisor"] = self.supervisor.stats()

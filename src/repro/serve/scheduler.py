@@ -17,9 +17,11 @@ control deadline can't steer anything.  So deadlines are first-class:
   the "millions of users" failure mode handled explicitly).
 
 Telemetry records the four lifecycle timestamps
-(enqueue -> admit -> dispatch -> deliver) on every request and rides
-back on ``PerceptionResult.telemetry``; latency percentiles in
-``benchmarks/serve_bench.py`` reduce over exactly these.
+(enqueue -> admit -> dispatch -> deliver) and the serving tick on every
+request and rides back on ``PerceptionResult.telemetry``; a caller's
+latency percentiles reduce over its own delivered requests' telemetry
+(as ``benchmarks/serve_bench.py`` does), and ``tick`` ties a request to
+its tick's ``serve.*`` profiler spans.
 """
 from __future__ import annotations
 
@@ -47,6 +49,8 @@ class RequestTelemetry:
     t_admit: float = 0.0       # packed into a staging slot
     t_dispatch: float = 0.0    # tick executable launched (compute start)
     t_deliver: float = 0.0     # result fetched back to the host
+    tick: int = -1             # serving tick that carried it (the
+                               # ``tick`` arg of its ``serve.*`` spans)
     deadline_missed: bool = False
     n_retries: int = 0         # re-dispatches after transient failures
     n_hedges: int = 0          # hedged duplicates launched past the SLO

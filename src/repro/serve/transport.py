@@ -31,6 +31,29 @@ from repro.configs.base import EncodingConfig, SNNConfig
 from repro.core.encoding import EventStream, fit_stream
 
 
+def _bank_layout(cfg: SNNConfig, batch: int, frame_hw: Tuple[int, int],
+                 event_capacity: int):
+    """``(shape, dtype)`` of every staging array, in upload order."""
+    H, W = frame_hw
+    ev = (batch, event_capacity)
+    return (((cfg.time_steps, batch, cfg.height, cfg.width,
+              cfg.in_channels), np.float32),
+            ((batch, H, W), np.float32),
+            EventStream(t=(ev, np.float32), x=(ev, np.int32),
+                        y=(ev, np.int32), p=(ev, np.int32),
+                        valid=(ev, bool)),
+            ((batch,), bool))
+
+
+def bank_nbytes(cfg: SNNConfig, batch: int, frame_hw: Tuple[int, int],
+                event_capacity: int) -> int:
+    """Bytes of one staging bank: what its upload moves to the device."""
+    voxels, bayer, events, from_events = _bank_layout(
+        cfg, batch, frame_hw, event_capacity)
+    return sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+               for shape, dtype in (voxels, bayer, *events, from_events))
+
+
 class StagingBank:
     """Host numpy slot buffers for one tick batch: DVS voxel windows,
     Bayer frames, per-slot bounded event FIFOs, and the per-slot
@@ -39,18 +62,12 @@ class StagingBank:
 
     def __init__(self, cfg: SNNConfig, batch: int,
                  frame_hw: Tuple[int, int], event_capacity: int):
-        H, W = frame_hw
-        self.voxels = np.zeros(
-            (cfg.time_steps, batch, cfg.height, cfg.width, cfg.in_channels),
-            np.float32)
-        self.bayer = np.zeros((batch, H, W), np.float32)
-        self.events = EventStream(
-            t=np.zeros((batch, event_capacity), np.float32),
-            x=np.zeros((batch, event_capacity), np.int32),
-            y=np.zeros((batch, event_capacity), np.int32),
-            p=np.zeros((batch, event_capacity), np.int32),
-            valid=np.zeros((batch, event_capacity), bool))
-        self.from_events = np.zeros((batch,), bool)
+        voxels, bayer, events, from_events = _bank_layout(
+            cfg, batch, frame_hw, event_capacity)
+        self.voxels = np.zeros(*voxels)
+        self.bayer = np.zeros(*bayer)
+        self.events = EventStream(*(np.zeros(*a) for a in events))
+        self.from_events = np.zeros(*from_events)
         # the slot pytree is built ONCE: staging mutates the arrays in
         # place, so the donated upload tuple never needs rebuilding on
         # the per-tick path (ISSUE 9 satellite — engine_tick staging

@@ -244,11 +244,16 @@ def test_fleet_stats_percentiles(setup):
     cfg, params = setup
     fleet = FleetEngine(params, cfg,
                         fleet_cfg=FleetConfig(batch=2, max_queue=16))
-    fleet.run_to_completion(_requests(cfg, 4))
+    done = fleet.run_to_completion(_requests(cfg, 4))
     st = fleet.stats()
     assert st["delivered"] == 4 and st["rejected"] == 0
     assert st["n_devices"] == 1
-    assert 0.0 < st["latency_p50_s"] <= st["latency_p99_s"]
+    # percentiles are the caller's, over its requests' telemetry
+    assert not any(k.startswith("latency") for k in st)
+    lat = sorted(r.telemetry.latency_s for r in done
+                 if r.status is RequestStatus.DONE)
+    assert len(lat) == 4
+    assert 0.0 < lat[int(0.50 * 4)] <= lat[min(3, int(0.99 * 4))]
 
 
 def test_fleet_rejects_batch_not_divisible_by_mesh(setup):

@@ -9,8 +9,9 @@ stacks are silent at init.
 
 Backend dispatch (``SNNConfig.backend``): the "jnp" path is the layered
 pure-XLA reference; "pallas" routes the hot path through
-``repro.kernels.ops`` — the activity-gated spike-im2col conv kernel for
-EVERY spiking conv (normal / strided / depthwise / 1x1), the fused
+``repro.kernels.ops`` — the spiking conv kernels for EVERY spiking conv
+(normal / strided / depthwise / 1x1: the direct conv where its shape
+rule allows, else the activity-gated spike-im2col conv), the fused
 norm+affine+LIF kernel after convs, the VMEM-resident LIF scan after
 dense layers, and the tile-skip spike matmul for dense layers whose
 inputs are spike tensors.  Forward is bit-exact across backends and
@@ -169,12 +170,14 @@ def spike_conv_jnp(xf, w, *, stride: int = 1, depthwise: bool = False):
     Both are bit-exact against the Pallas path and agree with
     ``_conv2d`` (lax.conv SAME) to float rounding.
 
-    Trade: the patch matrix transiently holds kh·kw copies of the
-    activation (both backends pay it — the kernel consumes the same
-    matrix), bought deliberately for cross-backend bit-parity and the
-    tile-skip lowering.  At this repo's frame sizes that is a few
-    hundred MB worst case; a formulation-free dense conv for memory-
-    constrained jnp-only use remains available as ``_conv2d``.
+    Trade: here the patch matrix transiently holds kh·kw copies of the
+    activation, bought deliberately for cross-backend bit-parity.  The
+    pallas backend's per-op conv builds the same K blocks in VMEM from
+    the activation instead (``spike_conv_direct_pallas``) wherever its
+    shape rule allows; the convs it does not take, the fused conv→LIF
+    kernel and the megakernel still consume a patch matrix.  A
+    formulation-free dense conv for memory-constrained jnp-only use
+    remains available as ``_conv2d``.
     """
     kh, kw = w.shape[:2]
     N = xf.shape[0]
@@ -203,10 +206,11 @@ def apply_spiking_conv(p, x, cfg: SNNConfig, *, stride: int = 1,
     stacks are silent at init (currents never cross v_th).
 
     Backend dispatch: under ``cfg.backend == "pallas"`` the conv itself
-    lowers through ``repro.kernels.ops.spike_conv_op`` — spike-im2col
-    into the activity-gated tile-skip matmul kernel (tap-loop kernel
-    for depthwise), where all-zero activation tiles skip their MXU
-    pass — and the norm+affine+LIF epilogue fuses into one
+    lowers through ``repro.kernels.ops.spike_conv_op`` — the direct
+    conv, or spike-im2col into the activity-gated tile-skip matmul
+    kernel (tap-loop kernel for depthwise), where all-zero activation
+    tiles skip their MXU pass — and the norm+affine+LIF epilogue fuses
+    into one
     VMEM-resident kernel.  The jnp path computes the identical
     K-blocked im2col / tap-loop formulation (``spike_conv_jnp``), so
     forward is bit-exact across backends; gating cannot perturb values

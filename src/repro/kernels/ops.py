@@ -10,9 +10,9 @@ and ``nlm_op`` here (lazily, from repro.isp.stages, so the pure-jnp
 path never imports Pallas), and the "pallas_fused" streaming backend's
 planner (repro.isp.fuse) executes its segments through
 ``pointwise_segment_op`` / ``stencil_segment_op``.  The SNN stack's "pallas" backend
-(``SNNConfig.backend``) resolves to ``spike_conv_op`` (the activity-
-gated spike-im2col conv) / ``norm_affine_lif_op`` / ``lif_scan_op`` /
-``spike_matmul_op`` from repro.core.layers.
+(``SNNConfig.backend``) resolves to ``spike_conv_op`` (the direct conv,
+or the activity-gated spike-im2col conv) / ``norm_affine_lif_op`` /
+``lif_scan_op`` / ``spike_matmul_op`` from repro.core.layers.
 
 The spiking ops carry a ``jax.custom_vjp`` whose backward implements
 the sigmoid surrogate gradient (BPTT through the LIF recurrence, à la
@@ -64,7 +64,9 @@ from repro.kernels.isp_fused import (pointwise_segment_pallas,
                                      stencil_segment_pallas)
 from repro.kernels.lif_scan import lif_scan_pallas, norm_affine_lif_pallas
 from repro.kernels.nlm import nlm_pallas
-from repro.kernels.spike_conv import (occupancy_mask, spike_conv_lif_pallas,
+from repro.kernels.spike_conv import (direct_conv_frames, occupancy_mask,
+                                      spike_conv_direct_pallas,
+                                      spike_conv_lif_pallas,
                                       spike_conv_pallas, spike_dwconv_pallas,
                                       tap_occupancy_mask)
 from repro.kernels.spike_matmul import spike_matmul_pallas
@@ -348,6 +350,61 @@ def _spike_conv_mm_bwd(gate, bm, bk, bn, res, g):
 _spike_conv_mm.defvjp(_spike_conv_mm_fwd, _spike_conv_mm_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _spike_conv_direct(xf, w, stride, gate, bm):
+    # "inline" gates in the kernel, on the block it has built in VMEM;
+    # "mask" (an occupancy pass over a patch matrix the direct path
+    # never builds) and "none" run dense — see spike_conv_direct_pallas
+    return spike_conv_direct_pallas(xf, w, stride=stride,
+                                    gated=(gate == "inline"), bm=bm,
+                                    interpret=blocks.interpret_mode())
+
+
+def _spike_conv_direct_fwd(xf, w, stride, gate, bm):
+    return _spike_conv_direct(xf, w, stride, gate, bm), (xf, w)
+
+
+def _spike_conv_direct_bwd(stride, gate, bm, res, g):
+    xf, w = res
+    kh, kw, cin, cout = w.shape
+    # the residual is the activation: rebuild the patches and take the
+    # patch path's adjoints (_spike_conv_mm_bwd, then im2col's own)
+    patches, im2col_vjp = jax.vjp(
+        lambda x: spike_im2col(x, kh, kw, stride)[0], xf)
+    wmat = w.reshape(kh * kw * cin, cout)
+    dx, = im2col_vjp(g @ wmat.T)
+    return dx, (patches.T @ g).reshape(w.shape)
+
+
+_spike_conv_direct.defvjp(_spike_conv_direct_fwd, _spike_conv_direct_bwd)
+
+
+# The trace-time tally of the spiking conv's two lowerings: shape key
+# -> "direct" or "patches", one entry per conv shape traced since the
+# last reset (a cached trace adds nothing).
+_CONV_PATHS: dict = {}
+
+
+def conv_paths() -> dict:
+    """Which lowering each traced spiking conv took: ``{key: path}``,
+    key ``tune.shape_key(op, F=frames, H=, W=, C=cin, O=cout, k=kernel,
+    s=stride)`` and path ``"direct"`` (``spike_conv_direct_pallas``)
+    or ``"patches"`` (an im2col patch matrix in HBM)."""
+    return dict(_CONV_PATHS)
+
+
+def reset_conv_paths() -> None:
+    _CONV_PATHS.clear()
+
+
+def direct_conv_rule(xshape, wshape, stride: int, depthwise: bool) -> bool:
+    """Whether a spiking conv takes the direct kernel: non-depthwise
+    and ``direct_conv_frames`` > 0 (``Wo % 8 == 0``; ``C`` divides 128
+    or is a multiple of it; one frame's blocks fit the VMEM budget).
+    Shapes only — no config key, tuned entry or flag enters it."""
+    return not depthwise and direct_conv_frames(xshape, wshape, stride) > 0
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _spike_dwconv(patches3, wflat, gate, bm):
     return spike_dwconv_pallas(patches3, wflat, gated=(gate != "none"),
@@ -371,8 +428,15 @@ _spike_dwconv.defvjp(_spike_dwconv_fwd, _spike_dwconv_bwd)
                                              "gate", "bm", "bk", "bn"))
 def _spike_conv_impl(xf, w, *, stride, depthwise, gate, bm, bk, bn):
     kh, kw = w.shape[:2]
-    N = xf.shape[0]
-    if depthwise:
+    N, H, W, C = xf.shape
+    direct = direct_conv_rule(xf.shape, w.shape, stride, depthwise)
+    key = tune.shape_key("spike_dwconv" if depthwise else "spike_conv",
+                         F=N, H=H, W=W, C=C, O=w.shape[3], k=kh, s=stride)
+    _CONV_PATHS[key] = "direct" if direct else "patches"
+    if direct:
+        Ho, Wo = _conv_out_hw(xf, kh, kw, stride)
+        y = _spike_conv_direct(xf, w, stride, gate, bm)
+    elif depthwise:
         patches3, (Ho, Wo) = dw_patches(xf, kh, kw, stride)
         y = _spike_dwconv(patches3, w.reshape(kh * kw, -1), gate, bm)
     else:
@@ -396,16 +460,19 @@ def spike_conv_op(xf, w, *, stride: int = 1, depthwise: bool = False,
     tensor; w: [kh, kw, cin, cout] HWIO weights (depthwise:
     [kh, kw, 1, C]) -> [N, Ho, Wo, cout], SAME padding.
 
-    Lowers via spike-im2col (``repro.core.layers.spike_im2col``) into
-    the tile-skip matmul kernels, so every conv kind — normal, strided,
-    depthwise, 1x1 — inherits the event-driven MXU-tile skip.
-    ``gate``: None (default) resolves the tuned gate mode for this
-    shape; "mask" forces the per-tile precomputed occupancy gate,
-    "inline" the spike_matmul kernel's in-kernel jnp.any check
-    (depthwise treats it as "mask"), "none" the dense baseline the
-    benchmark sweep compares against.  Launch tile shapes always come
-    from the tuned config.  Differentiable: plain matmul adjoints —
-    the surrogate gradient lives in the LIF epilogue downstream.
+    A normal conv whose shape passes ``direct_conv_rule`` runs the
+    direct kernel (``spike_conv_direct_pallas``: the patch matrix's K
+    blocks built in VMEM from the activation, none in HBM); the others
+    lower via spike-im2col (``repro.core.layers.spike_im2col``) into
+    the tile-skip matmul kernels.  Which way each traced shape went is
+    in ``conv_paths()``.  ``gate``: None (default) resolves the tuned
+    gate mode for this shape; "mask" forces the per-tile precomputed
+    occupancy gate, "inline" the in-kernel jnp.any check (depthwise
+    treats it as "mask"; the direct kernel runs "mask" dense), "none"
+    the dense baseline the benchmark sweep compares against.  Launch
+    tile shapes always come from the tuned config.  Differentiable:
+    plain matmul adjoints — the surrogate gradient lives in the LIF
+    epilogue downstream.
 
     Bit-exact vs the jnp reference ``spike_conv_jnp`` (shared canonical
     K-block / tap-loop formulation — for EVERY tuned block shape) and

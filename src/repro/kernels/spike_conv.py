@@ -21,12 +21,24 @@ tile's DMA as well as its MXU pass; in interpret mode the ``pl.when``
 still skips the dot, which is what the dense-vs-gated rows in
 ``benchmarks/npu_bench.py`` measure.
 
-Two kernels:
+The per-op kernels:
 
-``spike_conv_pallas`` — gated ``patches @ wmat`` for normal / strided /
-1x1 convs (depthwise uses the block-diagonal-free kernel below).
-Grid (M/bm, N/bn, K/bk), fp32 accumulation in VMEM scratch; a K-step
-whose ``occ[i, k]`` bit is clear contributes nothing.
+``spike_conv_direct_pallas`` — the normal / strided / 1x1 conv with no
+patch matrix in HBM.  Each program takes whole folded frames of the
+SAME-padded activation (a strided conv's split into its stride phases,
+so every tap window is a unit-stride slice) and builds each canonical
+128-wide K block of the patch matrix in VMEM: tap-major,
+channel-minor, as ``spike_im2col`` orders K, each tap window stored
+into its lane range, the K tail zero.  It then runs ``_conv_kernel``'s
+dot on the block and writes ``[rows, N]`` at the output's own width.
+``repro.kernels.ops`` sends a conv here by its shape
+(``direct_conv_frames``: ``Wo % 8 == 0``, channels that tile 128
+lanes, one frame's blocks within a VMEM budget).
+
+``spike_conv_pallas`` — gated ``patches @ wmat`` for the convs the
+direct kernel does not take (depthwise uses the block-diagonal-free
+kernel below).  Grid (M/bm, N/bn, K/bk), fp32 accumulation in VMEM
+scratch; a K-step whose ``occ[i, k]`` bit is clear contributes nothing.
 
 ``spike_dwconv_pallas`` — depthwise conv as a gated tap loop: patches
 ``[M, taps, C]`` stay in their per-channel form (a block-diagonal
@@ -45,7 +57,9 @@ blocked accumulation for EVERY legal launch config.  Sweeping block
 shapes changes the grid/gating granularity, never the float rounding —
 exactly like the norm reduce shape in ``lif_scan.py``.  A skipped
 tile's would-be contribution is exact zeros, so gating never changes
-the result either.
+the result either.  The direct kernel's dots take the very operands of
+the patch kernel's, in the same order, so the two lowerings are
+bit-identical.
 
 Tuning & fusion notes (ISSUE 8): launch shapes (``bm``/``bn``/``bk``),
 the gate mode, and the conv→LIF fusion boundary are per-(op, shape)
@@ -65,6 +79,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.layers import _same_pads
 from repro.kernels.blocks import (CANONICAL_K_BLOCK, DEFAULT_BK,
                                   DEFAULT_BM, DEFAULT_BN,
                                   canonical_k_slices, matmul_precision)
@@ -162,6 +177,239 @@ def spike_conv_pallas(patches, wmat, *, gated: bool = True, bm: int = BM,
         interpret=interpret,
     )(occ.reshape(-1), x, w)
     return y[:M, :N]
+
+
+# ---------------------------------------------------------------------------
+# Direct spike conv: canonical K blocks built in VMEM from the activation
+# ---------------------------------------------------------------------------
+
+# Rows a direct-conv program covers at most (whole frames per program,
+# as many as fit, so that a small layer still has several grid steps
+# for its input DMA to overlap).
+DIRECT_MAX_ROWS = 1024
+# VMEM a direct-conv program may hold, double-buffered blocks included
+# (Mosaic's default scoped limit on a v5e is 16 MiB).
+DIRECT_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _direct_geometry(H: int, W: int, kh: int, kw: int, stride: int):
+    """SAME geometry of the direct conv's phase-split input: (Ho, Wo,
+    (lo_h, hi_h), (lo_w, hi_w), Hs, Ws).  The activation is padded to
+    ``[stride·Hs, stride·Ws]`` (``hi`` may be negative: rows no tap
+    reads are cut) and split into ``stride²`` phases of ``[Hs, Ws]``,
+    so tap (i, j) reads phase ``(i % s, j % s)`` at the unit-stride
+    offset ``(i // s, j // s)``."""
+    lo_h, _, Ho = _same_pads(H, kh, stride)
+    lo_w, _, Wo = _same_pads(W, kw, stride)
+    Hs, Ws = (kh - 1) // stride + Ho, (kw - 1) // stride + Wo
+    return (Ho, Wo, (lo_h, stride * Hs - lo_h - H),
+            (lo_w, stride * Ws - lo_w - W), Hs, Ws)
+
+
+def _direct_vmem_bytes(f: int, *, s2: int, Hs: int, Ws: int, C: int,
+                       rows: int, Kp: int, N: int) -> int:
+    """VMEM one direct-conv program holds: its double-buffered input,
+    weight and output blocks (lane- and sublane-padded f32) plus the
+    K-block and accumulator scratch (a row chunk: at most
+    ``DIRECT_MAX_ROWS`` rows for any launch ``bm`` up to it)."""
+    lanes_c, lanes_n = _ceil_to(C, 128), _ceil_to(N, 128)
+    x = f * s2 * Hs * _ceil_to(Ws, 8) * lanes_c
+    w = Kp * lanes_n
+    y = f * rows * lanes_n
+    scratch = min(f * rows, DIRECT_MAX_ROWS) * (CANONICAL_K_BLOCK
+                                                + lanes_n)
+    return 4 * (2 * (x + w + y) + scratch)
+
+
+def direct_conv_frames(xshape, wshape, stride: int) -> int:
+    """Frames per program of the direct conv for input ``[N, H, W, C]``
+    and HWIO weights, or 0 where the shape keeps the patch path.
+
+    The rule reads shapes only:
+
+    * ``Wo % 8 == 0``: a tap window ``[frames, rows, Wo, C]`` collapses
+      into matmul rows without crossing the sublane tiling;
+    * ``128 % C == 0`` or ``C % 128 == 0``: every tap lies whole inside
+      one canonical K block, or every K block inside one tap, so no
+      block takes a channel sub-range at a lane offset;
+    * one frame's program fits ``DIRECT_VMEM_BUDGET``.
+
+    Frames per program: the largest divisor of N whose rows stay within
+    ``DIRECT_MAX_ROWS`` (at least one frame) and whose blocks fit the
+    budget.  Depthwise convs never ask (they have their own kernel)."""
+    N, H, W, C = xshape
+    kh, kw, _, cout = wshape
+    Ho, Wo, _, _, Hs, Ws = _direct_geometry(H, W, kh, kw, stride)
+    if Wo % 8 or not (CANONICAL_K_BLOCK % C == 0
+                      or C % CANONICAL_K_BLOCK == 0):
+        return 0
+    Kp = _ceil_to(kh * kw * C, CANONICAL_K_BLOCK)
+    rows = Ho * Wo
+    best = 0
+    for f in range(1, N + 1):
+        if N % f or (f > 1 and f * rows > DIRECT_MAX_ROWS):
+            continue
+        if _direct_vmem_bytes(f, s2=stride * stride, Hs=Hs, Ws=Ws, C=C,
+                              rows=rows, Kp=Kp,
+                              N=cout) > DIRECT_VMEM_BUDGET:
+            continue
+        best = f
+    return best
+
+
+def _direct_k_blocks(kh: int, kw: int, C: int, stride: int):
+    """Per canonical K block (tap-major, channel-minor — the order of
+    ``spike_im2col``): the tap windows it holds, each ``(phase, oi, oj,
+    c0, c1, lane)``, and the first lane no tap fills (zeros from there
+    on, as the patch path's K padding)."""
+    K = kh * kw * C
+    blocks = []
+    for k0 in range(0, K, CANONICAL_K_BLOCK):
+        k1 = min(k0 + CANONICAL_K_BLOCK, K)
+        parts = []
+        for t in range(kh * kw):
+            lo, hi = max(k0, t * C), min(k1, (t + 1) * C)
+            if lo >= hi:
+                continue
+            i, j = divmod(t, kw)
+            parts.append(((i % stride) * stride + j % stride,
+                          i // stride, j // stride,
+                          lo - t * C, hi - t * C, lo - k0))
+        blocks.append((tuple(parts), k1 - k0))
+    return tuple(blocks)
+
+
+def _largest_divisor(n: int, at_most: int) -> int:
+    return max(d for d in range(1, max(1, min(n, at_most)) + 1)
+               if n % d == 0)
+
+
+def _direct_row_chunks(f: int, Ho: int, Wo: int, bm: int):
+    """The program's row chunk ``(fc, hc, nh)``: ``fc`` frames × ``hc``
+    output rows, about ``bm`` matmul rows — a band of one frame (``nh``
+    bands a frame), or whole frames where a frame has fewer than ``bm``
+    rows.  Chunks are uniform, so the kernel loops over them rather
+    than unrolling each (its trace stays one chunk long); a chunk is
+    the gate's and the dot's granularity, as a ``bm`` row tile is the
+    patch kernel's."""
+    if Ho * Wo >= bm:
+        hc = _largest_divisor(Ho, bm // Wo)
+        return 1, hc, Ho // hc
+    return _largest_divisor(f, bm // (Ho * Wo)), Ho, 1
+
+
+def _direct_conv_kernel(x_ref, w_ref, y_ref, blk_ref, acc_ref, *, fc: int,
+                        hc: int, nh: int, kblocks, Wo: int, gated: bool,
+                        precision):
+    rows = fc * hc * Wo
+
+    def chunk(c, carry):
+        f0, h0 = (c // nh) * fc, (c % nh) * hc
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for kb, (parts, width) in enumerate(kblocks):
+            def window(p, oi, oj, c0, c1):
+                v = x_ref[pl.ds(f0, fc), p, pl.ds(h0 + oi, hc),
+                          oj:oj + Wo, c0:c1]
+                return v.reshape(rows, c1 - c0)
+
+            if len(parts) == 1 and width == CANONICAL_K_BLOCK:
+                # the block is one tap's aligned channel range
+                blk = window(*parts[0][:5])
+            else:
+                # assemble it lane range by lane range; the tail no tap
+                # fills is zero, as the patch path's K padding
+                for p, oi, oj, c0, c1, lane in parts:
+                    blk_ref[:, lane:lane + c1 - c0] = \
+                        window(p, oi, oj, c0, c1)
+                if width < CANONICAL_K_BLOCK:
+                    blk_ref[:, width:] = jnp.zeros(
+                        (rows, CANONICAL_K_BLOCK - width), blk_ref.dtype)
+                blk = blk_ref[...]
+
+            def mac(blk=blk, k0=kb * CANONICAL_K_BLOCK):
+                acc_ref[...] += jnp.dot(
+                    blk.astype(jnp.float32),
+                    w_ref[k0:k0 + CANONICAL_K_BLOCK, :].astype(jnp.float32),
+                    precision=precision, preferred_element_type=jnp.float32)
+
+            if gated:
+                # the activity gate, on the block already in VMEM
+                pl.when(jnp.any(blk != 0))(mac)
+            else:
+                mac()
+        r0 = pl.multiple_of(c * rows, 8)
+        y_ref[pl.ds(r0, rows), :] = acc_ref[...].astype(y_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, y_ref.shape[0] // rows, chunk, 0)
+
+
+def spike_conv_direct_pallas(xf, w, *, stride: int = 1, gated: bool = False,
+                             bm: int = BM, interpret: bool):
+    """SAME conv of the folded spike tensor xf [N, H, W, C] with HWIO
+    weights w [kh, kw, C, cout] -> [N·Ho·Wo, cout], without a patch
+    matrix in HBM.
+
+    The wrapper pads xf once (SAME, plus the rows that make the extent
+    a stride multiple) and, for ``stride > 1``, splits it into its
+    ``stride²`` phases in the same copy, so every tap window is a
+    unit-stride slice of one phase (Mosaic refuses strided value
+    slices).  Each program takes whole frames (``direct_conv_frames``)
+    and, per row chunk of about ``bm`` rows, builds every canonical K
+    block of the patch matrix in VMEM — the tap windows stored into
+    their lane ranges, the K tail zero — and runs the same dot as
+    ``_conv_kernel`` on it.  The dots' operands and the order the
+    blocks are added in are the patch path's and ``blocked_matmul``'s,
+    so the output is bit-identical to theirs.  The output is written at
+    its own width, ``cout`` lanes.
+
+    ``gated``: skip the dot of a (chunk, block) whose assembled block is
+    all zero (``jnp.any`` on the block in VMEM; its contribution is
+    exact zeros, so the gate changes no value).  Dense is the default:
+    the blocks' assembly, not the dot, is the kernel's work, and a gate
+    branch stalls it (on a TPU v5e the gated kernel was slower in every
+    cell of the chip benchmark, the sparse one included)."""
+    N, H, W, C = xf.shape
+    kh, kw, _, cout = w.shape
+    f = direct_conv_frames(xf.shape, w.shape, stride)
+    if not f:
+        raise ValueError(f"no direct conv for input {xf.shape}, weights "
+                         f"{w.shape}, stride {stride}")
+    Ho, Wo, (lo_h, hi_h), (lo_w, hi_w), Hs, Ws = _direct_geometry(
+        H, W, kh, kw, stride)
+    s = stride
+    xp = jax.lax.pad(xf, jnp.zeros((), xf.dtype),
+                     ((0, 0, 0), (lo_h, hi_h, 0), (lo_w, hi_w, 0),
+                      (0, 0, 0)))
+    xp = xp.reshape(N, Hs, s, Ws, s, C).transpose(0, 2, 4, 1, 3, 5)
+    xp = xp.reshape(N, s * s, Hs, Ws, C)
+    K = kh * kw * C
+    pk = (-K) % CANONICAL_K_BLOCK
+    wmat = w.reshape(K, cout)
+    if pk:
+        wmat = jnp.pad(wmat, ((0, pk), (0, 0)))
+    rows = f * Ho * Wo
+    fc, hc, nh = _direct_row_chunks(f, Ho, Wo, bm)
+    chunk_rows = fc * hc * Wo
+    return pl.pallas_call(
+        functools.partial(_direct_conv_kernel, fc=fc, hc=hc, nh=nh,
+                          kblocks=_direct_k_blocks(kh, kw, C, s), Wo=Wo,
+                          gated=gated, precision=matmul_precision()),
+        grid=(N // f,),
+        in_specs=[pl.BlockSpec((f, s * s, Hs, Ws, C),
+                               lambda n: (n, 0, 0, 0, 0)),
+                  pl.BlockSpec((K + pk, cout), lambda n: (0, 0))],
+        out_specs=pl.BlockSpec((rows, cout), lambda n: (n, 0)),
+        out_shape=jax.ShapeDtypeStruct((N * Ho * Wo, cout), w.dtype),
+        scratch_shapes=[pltpu.VMEM((chunk_rows, CANONICAL_K_BLOCK),
+                                   xf.dtype),
+                        pltpu.VMEM((chunk_rows, cout), jnp.float32)],
+        interpret=interpret,
+    )(xp, wmat)
 
 
 def _dwconv_kernel(occ_ref, x_ref, w_ref, y_ref, acc_ref, *, taps: int):

@@ -342,3 +342,151 @@ def test_sparsity_tape_summary():
     s = tape.summary()
     assert s["a"] == 0.5 and s["b"] == 0.0
     assert s["network_sparsity"] == 0.75
+
+
+# ---------------------------------------------------------------------------
+# the direct path: canonical K blocks built in VMEM from the activation
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def patch_path(monkeypatch):
+    """Turns the direct path off (the shape rule says no to every conv)
+    for the rest of the test.  Jit caches are cleared at each change of
+    rule, so no trace made with one rule serves the other, and the
+    trace-time tally sees every conv the test runs."""
+    def off():
+        jax.clear_caches()
+        monkeypatch.setattr(ops, "direct_conv_rule", lambda *a: False)
+    jax.clear_caches()
+    yield off
+    jax.clear_caches()
+
+
+def _fill(kind, shape):
+    if kind == "zeros":
+        return jnp.zeros(shape)
+    if kind == "ones":
+        return jnp.ones(shape)
+    return _spikes(shape, 0.2)
+
+
+# (cin, stride, H, W): odd extents, with Wo a multiple of 8
+DIRECT_SHAPES = [(2, 1, 13, 16), (2, 2, 13, 15), (32, 1, 9, 8),
+                 (32, 2, 15, 15), (64, 1, 7, 16), (64, 2, 13, 15),
+                 (128, 1, 5, 8), (128, 2, 9, 15)]
+
+
+@pytest.mark.parametrize("cin,stride,H,W", DIRECT_SHAPES)
+@pytest.mark.parametrize("gate", GATES)
+def test_spike_conv_direct_bitexact(cin, stride, H, W, gate, patch_path):
+    """The direct kernel is bit-identical to the jnp formulation and to
+    the patch path, under every gate, on sparse, silent and saturated
+    inputs."""
+    w = _w(3, 3, cin, 8)
+    xs = [_fill(k, (3, H, W, cin)) for k in ("spikes", "zeros", "ones")]
+    ops.reset_conv_paths()
+    got = [ops.spike_conv_op(x, w, stride=stride, gate=gate) for x in xs]
+    assert set(ops.conv_paths().values()) == {"direct"}
+    ref = jax.jit(lambda x, w: spike_conv_jnp(x, w, stride=stride))
+    for x, y in zip(xs, got):
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(ref(x, w)))
+    patch_path()
+    ops.reset_conv_paths()
+    for x, y in zip(xs, got):
+        via_patches = ops.spike_conv_op(x, w, stride=stride, gate=gate)
+        np.testing.assert_array_equal(np.asarray(y),
+                                      np.asarray(via_patches))
+    assert set(ops.conv_paths().values()) == {"patches"}
+
+
+@pytest.mark.parametrize("cin,stride,H,W", DIRECT_SHAPES[1:7:2]
+                         + DIRECT_SHAPES[6:7])
+def test_spike_conv_direct_grad_parity(cin, stride, H, W, patch_path):
+    """The direct path's custom VJP (residual: the activation) gives the
+    patch path's gradients, and the jnp path's to float rounding."""
+    xf = _spikes((2, H, W, cin), 0.2)
+    w = _w(3, 3, cin, 8)
+    wv = jnp.asarray(RNG.normal(
+        0, 1, (2, -(-H // stride), -(-W // stride), 8)).astype(np.float32))
+
+    def loss(fn):
+        return lambda x, w: jnp.sum(fn(x, w, stride=stride) * wv)
+
+    assert ops.direct_conv_rule(xf.shape, w.shape, stride, False)
+    g_d = jax.grad(loss(ops.spike_conv_op), argnums=(0, 1))(xf, w)
+    g_j = jax.grad(loss(spike_conv_jnp), argnums=(0, 1))(xf, w)
+    patch_path()
+    g_p = jax.grad(loss(ops.spike_conv_op), argnums=(0, 1))(xf, w)
+    for d, p, j in zip(g_d, g_p, g_j):
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(p))
+        assert _maxrel(d, j) <= 1e-5
+
+
+def test_conv_path_tally_yolo64():
+    """At the registered Spiking-YOLO (64x64, T=5, 32 to 256 channels)
+    and tick batch 8, the shape rule sends exactly d0 to f2 down the
+    direct path; d3, f3, the head's 3x3 conv (f3's shape) and its 1x1
+    readout (Wo = 4) keep the patch matrix."""
+    from repro.core.backbones import yolo_specs
+    from repro.kernels.backbone_fuse import layer_out_hw
+    cfg = dataclasses.replace(SNN_ARCHS["spiking_yolo"], backend="pallas")
+    B = 8
+    params = init_npu(jax.random.PRNGKey(0), cfg)
+    vox = jax.ShapeDtypeStruct((cfg.time_steps, B, cfg.height, cfg.width,
+                                cfg.in_channels), jnp.float32)
+    jax.clear_caches()                  # the tally counts traces
+    ops.reset_conv_paths()
+    jax.eval_shape(lambda p, v: npu_forward(p, v, cfg), params, vox)
+    paths = ops.conv_paths()
+    jax.clear_caches()
+    F, h, w = B * cfg.time_steps, cfg.height, cfg.width
+    want = {}
+    for s in yolo_specs(cfg):
+        key = f"spike_conv|C{s.cin},F{F},H{h},O{s.cout},W{w},k3,s{s.stride}"
+        want[key] = "direct" if s.name in ("d0", "f0", "d1", "f1", "d2",
+                                           "f2") else "patches"
+        h, w = layer_out_hw(s, h, w)
+    _, _, cin, cout = params["head"]["pred"]["w"].shape
+    want[f"spike_conv|C{cin},F{F},H{h},O{cout},W{w},k1,s1"] = "patches"
+    assert paths == want
+
+
+def test_serving_tick_direct_path_bit_identical(patch_path):
+    """A serving tick's detections, control vector and frame, on the
+    pallas backend in the interpreter, are bit-identical with the direct
+    path on and off, and to the same tick on the jnp backend."""
+    from repro.configs.base import FleetConfig
+    from repro.data.synthetic import make_scene_batch
+    from repro.serve.cognitive_engine import PerceptionRequest
+    from repro.serve.fleet import FleetEngine
+    cfg_j = reduced_snn("spiking_yolo")
+    cfg = reduced_snn("spiking_yolo", backend="pallas")
+    params = init_npu(jax.random.PRNGKey(5), cfg)
+    scene = make_scene_batch(jax.random.PRNGKey(6), batch=2,
+                             height=cfg.height, width=cfg.width,
+                             time_steps=cfg.time_steps, n_events=2048)
+
+    def tick(c):
+        fleet = FleetEngine(params, c, fleet_cfg=FleetConfig(
+            batch=2, max_queue=4, double_buffer=False))
+        done = fleet.run_to_completion([PerceptionRequest(
+            rid=i, events=jax.tree_util.tree_map(lambda a: a[i],
+                                                 scene.events),
+            bayer=scene.bayer[i]) for i in range(2)])
+        return [(np.asarray(s.request.result.raw_pred),
+                 np.asarray(s.request.result.control),
+                 np.asarray(s.request.result.rgb))
+                for s in sorted(done, key=lambda s: s.rid)]
+
+    ops.reset_conv_paths()
+    on = tick(cfg)
+    assert "direct" in ops.conv_paths().values()
+    ref = tick(cfg_j)
+    patch_path()
+    ops.reset_conv_paths()
+    off = tick(cfg)
+    assert "direct" not in ops.conv_paths().values()
+    for a, b, r in zip(on, off, ref):
+        for x, y, z in zip(a, b, r):
+            np.testing.assert_array_equal(x, y)
+            np.testing.assert_array_equal(x, z)

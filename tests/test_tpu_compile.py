@@ -100,6 +100,21 @@ def test_spike_conv_compiles(one_chip, layer):
         [((M, K), jnp.float32), ((K, s.cout), jnp.float32)], one_chip)
 
 
+@pytest.mark.parametrize("layer", ["d0", "f0", "d1", "f1", "d2", "f2"])
+def test_spike_conv_direct_compiles(one_chip, layer):
+    """The per-op spiking conv of every layer the shape rule sends down
+    the direct path (``ops.direct_conv_rule``), through ``spike_conv_op``
+    as the tick runs it: the pad and phase split, then the kernel."""
+    from repro.kernels import ops
+    cfg = _cfg()
+    s, h, w = _layer(yolo_specs(cfg), layer, cfg)
+    xs = (B * cfg.time_steps, h, w, s.cin)
+    ws = (s.kernel, s.kernel, s.cin, s.cout)
+    assert ops.direct_conv_rule(xs, ws, s.stride, False)
+    _assert_kernel(lambda x, k: ops.spike_conv_op(x, k, stride=s.stride),
+                   [(xs, jnp.float32), (ws, jnp.float32)], one_chip)
+
+
 @pytest.mark.parametrize("layer", ["d0", "f0", "f3"])
 def test_spike_conv_lif_compiles(one_chip, layer):
     """The fused conv→norm→LIF layer kernel (the ``conv_lif`` op's

@@ -135,7 +135,7 @@ def _compile_tick(engine):
                        core.enc_cfg.event_capacity)
     spec = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-        (core.params,) + bank.as_tuple())
+        (core.params, bank.slab, bank.voxels))
     t0 = time.perf_counter()
     compiled = core._step.lower(*spec).compile()
     return time.perf_counter() - t0, compiled.as_text()
@@ -327,12 +327,15 @@ def four_chips(seed: int):
     from repro.serve.transport import StagingBank
     bank = StagingBank(s["cfg"], BATCH, sharded.core.frame_hw,
                        s["enc"].event_capacity)
-    vox_dev = sharded.core.upload(bank.as_tuple())[0]   # [T, B, H, W, 2]
-    for shard in sorted(vox_dev.addressable_shards,
-                        key=lambda sh: sh.device.id):
-        print(f"bank voxels on device {shard.device.id}: batch slots "
-              f"{shard.index[1].start}:{shard.index[1].stop}, shard "
-              f"shape {tuple(shard.data.shape)}")
+    # one staged voxel slot, so the voxel slab goes up beside the slab
+    bank.stage_voxels(0, bank.voxels[:, 0], bank.bayer[0])
+    slab_dev, vox_dev = sharded.core.upload(bank)   # [B, words], [T, B, ...]
+    for name, arr, axis in (("slab", slab_dev, 0), ("voxels", vox_dev, 1)):
+        for shard in sorted(arr.addressable_shards,
+                            key=lambda sh: sh.device.id):
+            print(f"bank {name} on device {shard.device.id}: batch slots "
+                  f"{shard.index[axis].start}:{shard.index[axis].stop}, "
+                  f"shard shape {tuple(shard.data.shape)}")
     t0 = time.perf_counter()
     got, lat = _serve(sharded, _fresh(s["reqs"]))
     print(f"4-chip engine: {N_REQUESTS} requests in "

@@ -26,12 +26,14 @@ keep the batch full under ragged arrival.
 
 Zero-copy tick discipline (PR 3): submissions stage into HOST-side
 numpy slot buffers (a submit is a memcpy, no device dispatch), the tick
-uploads the whole staging bank with ONE ``jax.device_put`` and DONATES
-the buffers to the step executable; results come back with one batched
-``jax.device_get``.  The event path is part of the SAME tick
-executable: bounded per-slot FIFOs ride along as static-shape inputs
-and a traced per-slot flag selects encoded-vs-submitted voxels, so
-mixing ``submit`` and ``submit_events`` costs no retrace.
+uploads what its slots use with ONE ``jax.device_put`` — the slab of
+event windows, frames and flags, plus the voxel slab only when a slot
+staged voxels — and DONATES the slab to the step executable; results
+come back with one batched ``jax.device_get``.  The event path is part
+of the SAME tick executable: bounded per-slot FIFOs ride along as
+static-shape inputs and a traced per-slot flag selects
+encoded-vs-submitted voxels, so mixing ``submit`` and ``submit_events``
+costs no retrace.
 
 Configuration is unchanged: ``ISPConfig`` (stage ordering/backend,
 incl. ``"pallas_fused"`` megakernels), ``EncodingConfig`` (ingestion
@@ -183,10 +185,11 @@ class CognitiveEngine:
         if not any(r is not None for r in self.active):
             return []
         t0 = time.perf_counter()
-        # ONE host->device upload of the whole staging bank, ONE step
+        # ONE host->device upload of what the slots use, ONE step
         # launch, ONE batched device->host fetch (EngineCore.tick);
         # per-request results below are numpy views into the fetch
-        out, rgb, sp = self.core.tick(self.staging.as_tuple(), self.ticks)
+        out, rgb, sp = self.core.tick(self.staging, self.ticks)
+        self.staging.voxels_staged = False  # every slot is recycled
         self.last_tick_s = time.perf_counter() - t0
         self.ticks += 1
         # batch-level sparsity telemetry (one dict per tick, shared by

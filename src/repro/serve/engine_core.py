@@ -8,7 +8,10 @@ The engine-core/transport split (ROADMAP "fleet serving"):
   discipline, and (new) sharding the tick batch across a device mesh.
 * ``repro.serve.transport`` owns the host side — numpy staging banks a
   submit memcpys into, double-buffered so tick N+1's upload overlaps
-  tick N's compute.
+  tick N's compute.  A tick uploads only what its slots use: one
+  ``int32`` slab of event windows, frames and flags, and the voxel slab
+  only when a slot staged voxels (a zero buffer kept on the device
+  stands in for it otherwise).
 * ``repro.serve.scheduler`` owns request lifecycle — admission
   control, deadlines, telemetry.
 * ``repro.serve.fleet`` composes the three into the multi-device
@@ -17,8 +20,8 @@ The engine-core/transport split (ROADMAP "fleet serving"):
 
 Sharding: pass a 1-D ``("data",)`` mesh (see
 ``repro.launch.mesh.make_serving_mesh``) and the core replicates the
-NPU params once at construction and uploads every slot pytree with the
-batch dimension partitioned over the data axis
+NPU params once at construction and uploads the slab and the voxel
+slab with their batch dimension partitioned over the data axis
 (``repro.distributed.sharding.batch_sharding``).  The tick math is
 batch-parallel (per-slot instance norms, vmapped ISP), so the tick body
 runs once per shard under ``shard_map`` with no resharding; only the
@@ -29,9 +32,10 @@ pre-split engine.
 Tracing: ``upload`` and ``dispatch`` open the ``serve.upload`` and
 ``serve.dispatch`` profiler spans, and a caller opens ``fetch_span``,
 ``serve.fetch``, around ``fetch``: each carries the caller's ``tick``,
-the transfers their ``bytes``.  The tick body's operations carry the
-named scopes ``encode``, ``npu``, ``control`` and ``isp`` in their
-``op_name`` metadata.  Both land in a ``jax.profiler`` trace on one
+the transfers the ``bytes`` they move (``serve.upload`` also
+``voxels``, 1 when the voxel slab went up).  The tick body's operations
+carry the named scopes ``encode``, ``npu``, ``control`` and ``isp`` in
+their ``op_name`` metadata.  Both land in a ``jax.profiler`` trace on one
 clock; with no profiler running a span costs its enabled-check.
 """
 from __future__ import annotations
@@ -42,11 +46,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import EncodingConfig, ISPConfig, SNNConfig
-from repro.core.encoding import EventStream, events_to_voxel_batch
+from repro.core.encoding import events_to_voxel_batch
 from repro.core.npu import NPUOutput, npu_forward
 from repro.distributed.sharding import (MeshAxes, batch_sharding,
                                         from_mesh, replicated_sharding)
@@ -56,7 +61,7 @@ from repro.isp.stages import BACKENDS as ISP_BACKENDS
 from repro.isp.stages import control_to_stage_params
 from repro.kernels import tune
 from repro.kernels.blocks import matmul_precision
-from repro.serve.transport import bank_nbytes
+from repro.serve.transport import SlabLayout, bank_nbytes, voxel_shape
 
 
 def _precision_scope():
@@ -99,9 +104,11 @@ class EngineCore:
         self.batch = batch
         self.frame_hw: Tuple[int, int] = (
             frame_hw if frame_hw is not None else (cfg.height, cfg.width))
-        # bytes each transfer moves, for the spans: the staging bank's
-        # now; the tick's outputs once the first dispatch has their
-        # shapes
+        self.layout = SlabLayout(cfg, self.frame_hw,
+                                 self.enc_cfg.event_capacity)
+        # bytes each transfer moves, for the spans: an upload's without
+        # and with the voxel slab now; the tick's outputs once the first
+        # dispatch has their shapes
         self.upload_bytes = bank_nbytes(cfg, batch, self.frame_hw,
                                         self.enc_cfg.event_capacity)
         self.fetch_bytes: Optional[int] = None
@@ -129,23 +136,26 @@ class EngineCore:
             raise ValueError(
                 f"tick batch={batch} not divisible by the mesh's "
                 f"{self.n_devices} data-parallel devices")
-        self._slot_shardings = None
         self.params = npu_params
         if self.ax.mesh is not None:
-            # params replicated once at construction; every slot leaf
-            # partitioned over the data axis on its batch dim
+            # params replicated once at construction
             rep = replicated_sharding(self.ax)
             self.params = jax.device_put(npu_params, jax.tree_util.tree_map(
                 lambda _: rep, npu_params))
-            b0 = batch_sharding(self.ax, 0)
-            self._slot_shardings = (
-                batch_sharding(self.ax, 1),          # voxels [T,B,H,W,C]
-                b0,                                  # bayer  [B,H,W]
-                EventStream(t=b0, x=b0, y=b0, p=b0, valid=b0),
-                b0,                                  # from_events [B]
-            )
+        # the slab [B, words] and the voxel slab [T, B, H, W, C] are
+        # partitioned over the data axis on their batch dims (None
+        # without a mesh); indexed by whether the voxel slab goes up
+        slab_sh = batch_sharding(self.ax, 0)
+        vox_sh = batch_sharding(self.ax, 1)
+        self._upload_shardings = ((None, None) if slab_sh is None
+                                  else ((slab_sh,), (slab_sh, vox_sh)))
+        # what the tick reads as voxels when no slot staged any: made
+        # once, kept on the device, never donated
+        self._zero_voxels = jax.device_put(
+            np.zeros(voxel_shape(cfg, batch), np.float32), vox_sh)
 
         icfg, ncfg, ecfg, nd = self.isp_cfg, cfg, self.enc_cfg, need
+        layout = self.layout
         collect = bool(collect_sparsity)
 
         # Tune-table hoist (ISSUE 9 satellite): snapshot the active
@@ -179,11 +189,12 @@ class EngineCore:
                     oob=ecfg.oob)
             return jnp.moveaxis(vox, 0, 1)            # -> [T, B, H, W, 2]
 
-        def _step(params, voxels, bayer, events, from_events):
+        def _step(params, slab, voxels):
             # body runs at TRACE time only; ``pinned`` makes every op
             # dispatch inside resolve against the construction-time
             # table snapshot (zero per-tick resolution cost)
             with tune.pinned(self._tune_table), _precision_scope():
+                bayer, events, from_events = layout.unpack(slab)
                 # encode stage: voxelize the event slots inside the same
                 # executable (slots submitted as voxels keep their
                 # buffer); traced out entirely for non-DVS layouts
@@ -214,13 +225,13 @@ class EngineCore:
 
         # one executable serves every tick / control setting / ingestion
         # mix / mesh extent (the FPGA runtime-reconfigurability
-        # analogue).  The slot arguments are donated: the per-tick
-        # upload hands its device buffers to XLA for reuse, so
-        # steady-state serving holds one device copy of the slot state,
-        # not two.  (On backends without donation support this is a
-        # no-op warning, never an error.)
+        # analogue).  The slab is donated: the per-tick upload hands its
+        # device buffer to XLA for reuse.  The voxels are not: they may
+        # be the zero buffer, which serves every tick.  (On backends
+        # without donation support this is a no-op warning, never an
+        # error.)
         step = _step if self.ax.mesh is None else self._per_shard(_step)
-        self._step = jax.jit(step, donate_argnums=(1, 2, 3, 4))
+        self._step = jax.jit(step, donate_argnums=(1,))
 
     def _per_shard(self, step):
         """Run the tick body once per data shard (``shard_map``): XLA's
@@ -230,15 +241,13 @@ class EngineCore:
         mean of the per-shard values."""
         d = self.ax.dp_spec
         shard_b = P(d)
-        slot_specs = (P(None, d), shard_b, EventStream(*(shard_b,) * 5),
-                      shard_b)
         out_specs = (NPUOutput(raw_pred=shard_b, control=shard_b,
                                sparsity=None, tile_skip=None,
                                layer_rates=P()),
                      shard_b, shard_b)
 
-        def body(params, *slots):
-            out, rgb, sp = step(params, *slots)
+        def body(params, slab, voxels):
+            out, rgb, sp = step(params, slab, voxels)
             rates = out.layer_rates
             if rates is not None:
                 mean = functools.partial(jax.lax.pmean, axis_name=d)
@@ -247,31 +256,33 @@ class EngineCore:
             return out, rgb, sp
 
         return jax.shard_map(body, mesh=self.ax.mesh,
-                             in_specs=(P(),) + slot_specs,
+                             in_specs=(P(), shard_b, P(None, d)),
                              out_specs=out_specs, check_vma=False)
 
     # ------------------------------------------------------------------
     # ``tick`` is the caller's number for the tick, carried by the span
-    def upload(self, slots, tick: int = -1):
-        """ONE host->device transfer of a whole staging bank
-        ``(voxels, bayer, events, from_events)``; partitioned over the
-        mesh's data axis when sharded.  Returns device buffers ready to
-        be donated to :meth:`dispatch`."""
+    def upload(self, bank, tick: int = -1):
+        """ONE host->device transfer of what a staging bank's slots use:
+        its slab, and its voxel slab when a slot staged voxels;
+        partitioned over the mesh's data axis when sharded.  Returns
+        the device ``(slab, voxels)`` for :meth:`dispatch`, with the
+        zero buffer as ``voxels`` when they stayed on the host."""
+        slots = bank.as_tuple()
+        staged = len(slots) > 1
         with TraceAnnotation("serve.upload", tick=tick,
-                             bytes=self.upload_bytes):
-            if self._slot_shardings is None:
-                return jax.device_put(slots)
-            return jax.device_put(slots, self._slot_shardings)
+                             bytes=self.upload_bytes[staged],
+                             voxels=int(staged)):
+            dev = jax.device_put(slots, self._upload_shardings[staged])
+        return dev if staged else (dev[0], self._zero_voxels)
 
     def dispatch(self, slots_dev, tick: int = -1):
         """Launch the tick executable on uploaded slot buffers.  JAX
         dispatch is asynchronous: this returns futures immediately, so a
         caller may upload the NEXT bank while this tick computes (the
         double-buffer overlap ``repro.serve.fleet`` exploits)."""
-        voxels, bayer, events, from_events = slots_dev
+        slab, voxels = slots_dev
         with TraceAnnotation("serve.dispatch", tick=tick):
-            outputs = self._step(self.params, voxels, bayer, events,
-                                 from_events)
+            outputs = self._step(self.params, slab, voxels)
         if self.fetch_bytes is None:        # the outputs' shapes, once
             self.fetch_bytes = sum(
                 x.nbytes for x in jax.tree_util.tree_leaves(outputs))
@@ -290,9 +301,9 @@ class EngineCore:
         return TraceAnnotation("serve.fetch", tick=tick,
                                bytes=self.fetch_bytes or 0)
 
-    def tick(self, slots, tick: int = -1):
-        """upload -> dispatch -> fetch in one call (the unpipelined
-        path ``CognitiveEngine`` uses)."""
-        outputs = self.dispatch(self.upload(slots, tick), tick)
+    def tick(self, bank, tick: int = -1):
+        """upload -> dispatch -> fetch of a staging bank in one call
+        (the unpipelined path ``CognitiveEngine`` uses)."""
+        outputs = self.dispatch(self.upload(bank, tick), tick)
         with self.fetch_span(tick):
             return self.fetch(outputs)

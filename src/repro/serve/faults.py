@@ -159,14 +159,16 @@ class FaultInjector:
         return getattr(self._core, name)
 
     # -- intercepted boundary ------------------------------------------
-    def upload(self, slots, tick: int = -1):
+    def upload(self, bank, tick: int = -1):
         fault_tick = self._ticker.tick
         for ev in self._plan.events_at(fault_tick):
             if ev.kind is FaultKind.CORRUPT_INPUT:
-                voxels = slots[0]
+                # the poison reaches the tick where the slot staged
+                # voxels (they then go up); an event slot never reads it
+                voxels = bank.voxels
                 voxels[:, ev.slot % voxels.shape[1]] = ev.value
                 self.n_injected += 1
-        return self._core.upload(slots, tick)
+        return self._core.upload(bank, tick)
 
     def dispatch(self, slots_dev, tick: int = -1):
         fault_tick = self._ticker.tick

@@ -280,6 +280,7 @@ class FleetEngine:
         bank the queue can; returns the packed ``(slot, request)``
         pairs."""
         bank = self.buffers.front
+        bank.voxels_staged = False          # packed anew from here
         packed: List[Tuple[int, ServeRequest]] = []
         while len(packed) < self.batch and len(self.queue):
             sreq = self.queue.pop_ready(now)
@@ -325,7 +326,7 @@ class FleetEngine:
         core = self.cores[rung]
         try:
             # ONE device_put, then the asynchronous launch
-            dev = core.upload(self.buffers.front.as_tuple(), tick)
+            dev = core.upload(self.buffers.front, tick)
             outputs = core.dispatch(dev, tick)
         except TransientTickError as e:
             t_fail = self.clock()
@@ -520,7 +521,7 @@ class FleetEngine:
                            self.core.enc_cfg.event_capacity)
         for core in self.cores:
             real = getattr(core, "_core", core)  # bypass fault injection
-            real.fetch(real.dispatch(real.upload(bank.as_tuple())))
+            real.fetch(real.dispatch(real.upload(bank)))
 
     def drain(self, max_steps: int = 10000) -> List[ServeRequest]:
         """Step until the queue and the pipeline are empty; returns

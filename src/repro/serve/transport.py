@@ -3,9 +3,16 @@ banks and the double-buffer that overlaps upload with compute.
 
 A submit is a memcpy into a :class:`StagingBank` slot — no device
 dispatch (the zero-copy discipline PR 3 established; asserted by the
-dispatch-counting engine test).  ``EngineCore.upload`` later moves a
-whole bank with ONE ``jax.device_put`` and donates the device buffers
+dispatch-counting engine test).  ``EngineCore.upload`` later moves the
+bank with ONE ``jax.device_put`` and donates the slab's device buffer
 to the tick executable.
+
+What a tick uploads is only what its slots use.  Every slot's event
+window, Bayer frame and ``from_events`` flag sit in one row of an
+``int32`` slab (:class:`SlabLayout`), so they go up as one array.  The
+voxel slab stays an array of its own and goes up only in a tick in
+which some packed slot staged voxels; other ticks read a zero buffer
+that the engine core keeps on the device.
 
 :class:`DoubleBuffer` holds TWO banks.  While tick N computes on the
 device buffers uploaded from bank A (already donated — the host copy in
@@ -25,60 +32,108 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.configs.base import EncodingConfig, SNNConfig
 from repro.core.encoding import EventStream, fit_stream
 
+SLAB_ALIGN = 128            # a slab row is padded to whole 128-lane tiles
 
-def _bank_layout(cfg: SNNConfig, batch: int, frame_hw: Tuple[int, int],
+
+class SlabLayout:
+    """Where each staged input sits in a slot's row of the ``int32``
+    slab: the event window (``t`` as float32 bits, then ``x``, ``y``,
+    ``p``, and ``valid`` as 0/1, ``event_capacity`` words each; only
+    when the NPU takes DVS events, ``in_channels == 2``), the Bayer
+    frame as float32 bits, ``H * W`` words, and the ``from_events``
+    flag, one word.  ``sections`` maps each name to its static
+    ``(offset, words)``; ``words`` is the row, padded to
+    ``SLAB_ALIGN``."""
+
+    def __init__(self, cfg: SNNConfig, frame_hw: Tuple[int, int],
                  event_capacity: int):
-    """``(shape, dtype)`` of every staging array, in upload order."""
-    H, W = frame_hw
-    ev = (batch, event_capacity)
-    return (((cfg.time_steps, batch, cfg.height, cfg.width,
-              cfg.in_channels), np.float32),
-            ((batch, H, W), np.float32),
-            EventStream(t=(ev, np.float32), x=(ev, np.int32),
-                        y=(ev, np.int32), p=(ev, np.int32),
-                        valid=(ev, bool)),
-            ((batch,), bool))
+        self.frame_hw = tuple(frame_hw)
+        sizes = ([(name, event_capacity) for name in EventStream._fields]
+                 if cfg.in_channels == 2 else [])
+        sizes += [("bayer", self.frame_hw[0] * self.frame_hw[1]),
+                  ("from_events", 1)]
+        self.sections, off = {}, 0
+        for name, n in sizes:
+            self.sections[name] = (off, n)
+            off += n
+        self.words = -(-off // SLAB_ALIGN) * SLAB_ALIGN
+        self.has_events = cfg.in_channels == 2
+
+    def _split(self, slab, as_f32, as_flag):
+        """``(bayer, events, from_events)`` cut from ``slab`` at the
+        static offsets; ``events`` is None without an event section."""
+        def cut(name):
+            off, n = self.sections[name]
+            return slab[:, off:off + n]
+        bayer = as_f32(cut("bayer")).reshape(slab.shape[0], *self.frame_hw)
+        events = None
+        if self.has_events:
+            events = EventStream(t=as_f32(cut("t")), x=cut("x"), y=cut("y"),
+                                 p=cut("p"), valid=as_flag(cut("valid")))
+        return bayer, events, as_flag(cut("from_events")[:, 0])
+
+    def views(self, slab: np.ndarray):
+        """Writable numpy views into a host slab (flags as int32 0/1)."""
+        return self._split(slab, lambda a: a.view(np.float32),
+                           lambda a: a)
+
+    def unpack(self, slab):
+        """The device side of :meth:`views`, on a traced slab: float
+        sections bitcast back and flags as bool — bit for bit what was
+        staged."""
+        return self._split(
+            slab, lambda a: lax.bitcast_convert_type(a, jnp.float32),
+            lambda a: a != 0)
+
+
+def voxel_shape(cfg: SNNConfig, batch: int) -> Tuple[int, ...]:
+    """The voxel slab ``[T, B, H, W, C]``."""
+    return (cfg.time_steps, batch, cfg.height, cfg.width, cfg.in_channels)
 
 
 def bank_nbytes(cfg: SNNConfig, batch: int, frame_hw: Tuple[int, int],
-                event_capacity: int) -> int:
-    """Bytes of one staging bank: what its upload moves to the device."""
-    voxels, bayer, events, from_events = _bank_layout(
-        cfg, batch, frame_hw, event_capacity)
-    return sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
-               for shape, dtype in (voxels, bayer, *events, from_events))
+                event_capacity: int) -> Tuple[int, int]:
+    """Bytes one upload of a staging bank moves: the slab alone, and
+    the slab with the voxel slab."""
+    slab = 4 * batch * SlabLayout(cfg, frame_hw, event_capacity).words
+    return slab, slab + 4 * int(np.prod(voxel_shape(cfg, batch)))
 
 
 class StagingBank:
-    """Host numpy slot buffers for one tick batch: DVS voxel windows,
-    Bayer frames, per-slot bounded event FIFOs, and the per-slot
-    encoded-vs-submitted flag.  Inactive slots carry zeros and ride
-    along in the fixed-shape executable."""
+    """Host numpy slot buffers for one tick batch: the slab that holds
+    every slot's event FIFO, Bayer frame and encoded-vs-submitted flag
+    (``bayer``, ``events`` and ``from_events`` are views into it), and
+    the DVS voxel windows.  Inactive slots ride along in the
+    fixed-shape executable; their outputs are never read.
+
+    ``voxels_staged`` records whether a slot packed since the last
+    reset staged voxels, and so whether the upload carries the voxel
+    slab; whoever packs the bank anew resets it."""
 
     def __init__(self, cfg: SNNConfig, batch: int,
                  frame_hw: Tuple[int, int], event_capacity: int):
-        voxels, bayer, events, from_events = _bank_layout(
-            cfg, batch, frame_hw, event_capacity)
-        self.voxels = np.zeros(*voxels)
-        self.bayer = np.zeros(*bayer)
-        self.events = EventStream(*(np.zeros(*a) for a in events))
-        self.from_events = np.zeros(*from_events)
-        # the slot pytree is built ONCE: staging mutates the arrays in
-        # place, so the donated upload tuple never needs rebuilding on
-        # the per-tick path (ISSUE 9 satellite — engine_tick staging
-        # overhead)
-        self._tuple = (self.voxels, self.bayer, self.events,
-                       self.from_events)
+        self.layout = SlabLayout(cfg, frame_hw, event_capacity)
+        self.slab = np.zeros((batch, self.layout.words), np.int32)
+        self.voxels = np.zeros(voxel_shape(cfg, batch), np.float32)
+        self.bayer, self.events, self.from_events = self.layout.views(
+            self.slab)
+        self.voxels_staged = False
+        # both upload tuples are built ONCE: staging mutates the arrays
+        # in place, so the per-tick path never rebuilds them
+        self._uploads = ((self.slab,), (self.slab, self.voxels))
 
     def stage_voxels(self, slot: int, voxels, bayer) -> None:
         self.voxels[:, slot] = np.asarray(voxels, np.float32)
         self.bayer[slot] = np.asarray(bayer, np.float32)
         self.from_events[slot] = False
+        self.voxels_staged = True
 
     def stage_events(self, slot: int, ev: EventStream, bayer) -> None:
         """``ev`` must already fit the bank's FIFO capacity (see
@@ -92,10 +147,11 @@ class StagingBank:
         self.from_events[slot] = True
 
     def as_tuple(self):
-        """The slot pytree in ``EngineCore.upload`` argument order —
-        the SAME tuple object every call (slots mutate in place), so
-        per-tick staging is zero-allocation on the host side."""
-        return self._tuple
+        """What ``EngineCore.upload`` moves: ``(slab,)``, or ``(slab,
+        voxels)`` when a slot staged voxels — the SAME tuple objects
+        every call (slots mutate in place), so per-tick staging is
+        zero-allocation on the host side."""
+        return self._uploads[self.voxels_staged]
 
 
 class DoubleBuffer:

@@ -277,8 +277,9 @@ def test_event_path_scenarios_through_engine(setup):
 
 def test_engine_tick_issues_single_device_put(setup, monkeypatch):
     """Zero-copy tick contract: a submit is a host-side memcpy (no
-    device dispatch at all), and the tick uploads the whole staging
-    area with exactly ONE jax.device_put."""
+    device dispatch at all), and the tick uploads what its slots use
+    (the slab, and the voxel slab these voxel slots staged) with
+    exactly ONE jax.device_put."""
     cfg, params = setup
     eng = CognitiveEngine(params, cfg, batch=2)
     reqs = _requests(cfg, 4)
@@ -290,7 +291,7 @@ def test_engine_tick_issues_single_device_put(setup, monkeypatch):
     real = jax.device_put
 
     def counting(*a, **k):
-        calls.append(1)
+        calls.append(a[0])
         return real(*a, **k)
 
     monkeypatch.setattr(jax, "device_put", counting)
@@ -299,7 +300,9 @@ def test_engine_tick_issues_single_device_put(setup, monkeypatch):
     assert len(calls) == 0                     # staging is host-side
     done = eng.tick()
     assert len(done) == 2
-    assert len(calls) == 1                     # one upload per tick
+    up, = calls                                # one upload per tick
+    assert len(up) == 2 and up[0] is eng.staging.slab \
+        and up[1] is eng.staging.voxels
 
 
 def test_engine_with_pallas_npu_backend_matches_jnp(setup):
@@ -359,17 +362,22 @@ def test_engine_with_fused_isp_backend_matches_jnp(setup):
 # ---------------------------------------------------------------------------
 
 def test_staging_bank_tuple_is_prebuilt(setup):
-    """``as_tuple()`` returns the SAME tuple object every call (slots
-    mutate in place) — the donated upload pytree is never rebuilt on
-    the per-tick path."""
+    """``as_tuple()`` returns one of two tuples built once (slots
+    mutate in place): ``(slab,)``, or ``(slab, voxels)`` once a slot
+    staged voxels — the upload arguments are never rebuilt on the
+    per-tick path."""
     cfg, params = setup
     eng = CognitiveEngine(params, cfg, batch=2)
     t0 = eng.staging.as_tuple()
     assert eng.staging.as_tuple() is t0
+    assert len(t0) == 1 and t0[0] is eng.staging.slab
     assert eng.submit(_requests(cfg, 1, seed=17)[0])
     t1 = eng.staging.as_tuple()            # staging mutated in place
-    assert t1 is t0 and t1[0] is eng.staging.voxels
-    assert bool(np.any(t1[0]))
+    assert eng.staging.as_tuple() is t1
+    assert t1[0] is eng.staging.slab and t1[1] is eng.staging.voxels
+    assert bool(np.any(t1[1]))
+    eng.tick()                             # every slot recycled
+    assert eng.staging.as_tuple() is t0
 
 
 def test_engine_pallas_tick_pinned_table_no_retrace(setup):

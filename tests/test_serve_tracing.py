@@ -118,19 +118,29 @@ def test_step_spans_nest_in_order_and_tie_requests_to_ticks(setup,
 
 
 def test_transfer_spans_carry_the_bytes_moved(setup, tmp_path):
+    """Tick 7 stages no voxels and moves the slab alone; tick 8 stages
+    one voxel slot and moves the voxel slab besides."""
     cfg, params = setup
     core = EngineCore(params, cfg, batch=2)
     bank = StagingBank(cfg, 2, core.frame_hw, core.enc_cfg.event_capacity)
-    out = core.tick(bank.as_tuple())            # compiles outside
+    out = core.tick(bank)                       # compiles outside
+    moved = {7: sum(a.nbytes for a in bank.as_tuple())}
     with jax.profiler.trace(str(tmp_path)):
-        core.tick(bank.as_tuple(), 7)
-    spans = {s[0]: s[3] for s in _serve_spans(str(tmp_path))}
-    assert set(spans) == {"serve.upload", "serve.dispatch", "serve.fetch"}
-    assert all(a["tick"] == 7 for a in spans.values())
-    assert spans["serve.upload"]["bytes"] == sum(
-        a.nbytes for a in jax.tree_util.tree_leaves(bank.as_tuple()))
-    assert spans["serve.fetch"]["bytes"] == sum(
+        core.tick(bank, 7)
+        bank.stage_voxels(0, bank.voxels[:, 0], bank.bayer[0])
+        moved[8] = sum(a.nbytes for a in bank.as_tuple())
+        core.tick(bank, 8)
+    spans = _serve_spans(str(tmp_path))
+    assert [s[0] for s in spans] == ["serve.upload", "serve.dispatch",
+                                     "serve.fetch"] * 2
+    assert [s[3]["tick"] for s in spans] == [7] * 3 + [8] * 3
+    uploads = [s[3] for s in spans if s[0] == "serve.upload"]
+    assert [(a["bytes"], a["voxels"]) for a in uploads] == [
+        (moved[7], 0), (moved[8], 1)]
+    assert moved[8] == moved[7] + bank.voxels.nbytes
+    assert all(s[3]["bytes"] == sum(
         np.asarray(a).nbytes for a in jax.tree_util.tree_leaves(out))
+        for s in spans if s[0] == "serve.fetch")
 
 
 @pytest.mark.parametrize("batch,frame_hw,capacity",
@@ -141,8 +151,12 @@ def test_bank_nbytes_is_the_staging_bank_size(setup, batch, frame_hw,
     cfg, _ = setup
     hw = frame_hw or (cfg.height, cfg.width)
     bank = StagingBank(cfg, batch, hw, capacity)
-    assert bank_nbytes(cfg, batch, hw, capacity) == sum(
-        a.nbytes for a in jax.tree_util.tree_leaves(bank.as_tuple()))
+    slab_only = sum(a.nbytes for a in bank.as_tuple())
+    bank.stage_voxels(0, bank.voxels[:, 0], bank.bayer[0])
+    with_voxels = sum(a.nbytes for a in bank.as_tuple())
+    assert bank_nbytes(cfg, batch, hw, capacity) == (slab_only,
+                                                     with_voxels)
+    assert slab_only == 4 * batch * bank.layout.words
 
 
 def test_cognitive_engine_ticks_carry_their_number(setup, tmp_path):
@@ -162,7 +176,7 @@ def test_tick_hlo_carries_stage_scopes(setup):
     cfg, params = setup
     core = EngineCore(params, cfg, batch=2)
     bank = StagingBank(cfg, 2, core.frame_hw, core.enc_cfg.event_capacity)
-    text = core._step.lower(core.params, *bank.as_tuple()).as_text(
+    text = core._step.lower(core.params, bank.slab, bank.voxels).as_text(
         dialect="hlo", debug_info=True)
     names = re.findall(r'op_name="([^"]*)"', text)
     # (``control`` is a no-op slice where the head is as wide as the
@@ -184,7 +198,7 @@ def test_serving_tick_returns_no_training_scalars(setup):
     for i, r in enumerate(reqs):
         bank.stage_voxels(i, r.voxels, r.bayer)
     vox = np.array(bank.voxels)
-    out, _, _ = core.tick(bank.as_tuple())
+    out, _, _ = core.tick(bank)
     assert out.sparsity is None and out.tile_skip is None
     ref = jax.jit(lambda p, v: npu_forward(p, v, cfg))(params, vox)
     np.testing.assert_array_equal(out.raw_pred, np.asarray(ref.raw_pred))

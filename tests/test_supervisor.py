@@ -184,7 +184,7 @@ def test_ladder_rungs_bit_parity(setup):
     bank = fleet.buffers.front
     for i in range(2):
         bank.stage_voxels(i, vox[:, i], scene.bayer[i])
-    outs = [core.tick(bank.as_tuple()) for core in fleet.cores]
+    outs = [core.tick(bank) for core in fleet.cores]
     ref_out, ref_rgb, _ = outs[0]
     for out, rgb, _ in outs[1:]:
         np.testing.assert_allclose(np.asarray(out.raw_pred),
@@ -209,7 +209,8 @@ def test_jnp_rung_takes_the_kernels_matmul_precision(setup, monkeypatch):
                       batch=2)
     bank = StagingBank(core.cfg, core.batch, core.frame_hw,
                        core.enc_cfg.event_capacity)
-    text = core._step.lower(core.params, *bank.as_tuple()).as_text()
+    text = core._step.lower(core.params, bank.slab,
+                            bank.voxels).as_text()
     dots = re.findall(r"stablehlo\.(?:dot_general|convolution)\b.*", text)
     assert dots and all("HIGHEST" in d for d in dots), dots
 
